@@ -372,15 +372,17 @@ class TestErrors:
             resolve_out_sharding(mesh, out_sharding="replicate-please")
 
     def test_unsupported_sharding_flavor_rejected(self):
-        """A PositionalSharding-style flavor gives the unit-axis
-        padding nothing to derive from — it must be rejected loudly,
-        not crash with a raw jax divisibility error mid-gather."""
-        from jax.sharding import PositionalSharding
+        """A sharding flavor other than Named/SingleDevice gives the
+        unit-axis padding nothing to derive from — it must be rejected
+        loudly, not crash with a raw jax divisibility error
+        mid-gather."""
+
+        class _OtherSharding(jax.sharding.Sharding):
+            """Any Sharding subclass the gather cannot pad for."""
 
         mesh = make_mesh(2, sp=1)
-        pos = PositionalSharding(jax.local_devices()[:2])
         with pytest.raises(ValueError, match="NamedSharding"):
-            resolve_out_sharding(mesh, out_sharding=pos)
+            resolve_out_sharding(mesh, out_sharding=_OtherSharding())
 
 
 class TestDeviceReadSurface:

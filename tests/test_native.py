@@ -528,6 +528,32 @@ def test_native_library_builds_when_compiler_available():
         "(check cc errors on tpuparquet/native/*.c)"
 
 
+@pytest.mark.parametrize("carried", ["no-stamp", "stale-stamp"])
+def test_native_rebuilds_a_library_not_built_from_these_sources(
+        tmp_path, monkeypatch, carried):
+    """A .so carried in from elsewhere (no stamp, or a stamp for other
+    sources) is rebuilt, whatever its mtime; a matching stamp is
+    trusted."""
+    import os
+
+    import tpuparquet.native as native
+
+    so = tmp_path / "_tpq_native.so"
+    monkeypatch.setattr(native, "_SO", str(so))
+    monkeypatch.setattr(native, "_STAMP", str(so) + ".sha256")
+    so.write_bytes(b"not a library")
+    os.utime(so, (2**31, 2**31))  # newer than any source
+    if carried == "stale-stamp":
+        (tmp_path / "_tpq_native.so.sha256").write_text("0" * 64)
+    assert native._build()
+    assert so.read_bytes()[:4] == b"\x7fELF"
+    assert ((tmp_path / "_tpq_native.so.sha256").read_text()
+            == native._source_hash())
+    built = so.stat().st_mtime_ns
+    assert native._build()
+    assert so.stat().st_mtime_ns == built  # matching stamp: no rebuild
+
+
 class TestNativeHybridEncode:
     def test_byte_identical_to_python(self):
         from unittest import mock

@@ -1,0 +1,197 @@
+"""Ahead-of-time compiles of the main path's kernels for a described
+TPU v5e: what the chip's compiler would refuse fails here, at no chip
+time.  Nothing runs, so nothing here says anything about results.
+
+The topology is described inside a module-scoped fixture, never at
+import: only one process at a time may load the TPU library, and every
+xdist worker imports this file.  The persistent compilation cache is
+off around these compiles (an entry compiled for a described chip
+cannot be read back without one).  Shapes come from the host planners
+run on real encoded streams at 1M values.
+"""
+
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P, \
+    SingleDeviceSharding
+
+N = 1 << 20  # values per kernel call: a full-size page batch
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this image
+        jax.config.update("jax_enable_compilation_cache", was)
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield t
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _spec(a, sharding):
+    a = np.asarray(a)
+    return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
+
+
+def _compile(fn, *args, **static):
+    compiled = jax.jit(fn, static_argnames=tuple(static)).lower(
+        *args, **static).compile()
+    return compiled.as_text()
+
+
+def _hybrid_plan(width: int, count: int = N):
+    from tpuparquet.cpu.dictionary import encode_dict_indices
+    from tpuparquet.kernels.hybrid import plan_hybrid
+
+    rng = np.random.default_rng(width)
+    # runs of repeats between random stretches: both RLE and bit-packed
+    # runs, as a real dictionary-index page has
+    idx = rng.integers(0, 1 << width, size=count, dtype=np.uint32)
+    idx[: count // 4] = 1
+    stream = encode_dict_indices(idx, 1 << width)
+    assert stream[0] == width
+    return plan_hybrid(stream[1:], count, width)
+
+
+@pytest.mark.parametrize("width", [3, 17])
+def test_expand_hybrid_core(one_chip, width):
+    from tpuparquet.kernels.hybrid import expand_hybrid_core, pad_plan
+
+    arrays, cnt, w, n_bp = pad_plan(_hybrid_plan(width))
+    idx = jax.ShapeDtypeStruct((cnt,), jnp.int32, sharding=one_chip)
+    text = _compile(expand_hybrid_core,
+                    *[_spec(a, one_chip) for a in arrays], idx,
+                    width=w, n_bp=n_bp)
+    assert "HloModule" in text
+
+
+def test_dict_gather_fixed(one_chip):
+    from tpuparquet.kernels.decode import dict_gather_fixed
+
+    lanes = 2
+    dictionary = jax.ShapeDtypeStruct((4096 * lanes,), jnp.uint32,
+                                      sharding=one_chip)
+    indices = jax.ShapeDtypeStruct((N,), jnp.int32, sharding=one_chip)
+    _compile(dict_gather_fixed, dictionary, indices, lanes=lanes)
+
+
+def test_expand_delta_i64(one_chip):
+    """At 64K values: the 64-bit associative scan takes the TPU compiler
+    over a minute at 1M (an open question in PERF.md), too long for a
+    tier-1 test."""
+    from tpuparquet.cpu.delta import encode_delta_binary_packed
+    from tpuparquet.kernels.decode import (DeltaPlan, expand_delta_i64,
+                                           plan_delta_i64)
+
+    rng = np.random.default_rng(1)
+    n = 1 << 16
+    ts = 1_700_000_000_000 + rng.integers(0, 3_600_000, size=n).cumsum()
+    plan = plan_delta_i64(np.frombuffer(
+        encode_delta_binary_packed(ts.astype(np.int64)), dtype=np.uint8))
+    # every host array of the plan becomes an argument of the program
+    host = [(g[1], g[2], g[3]) for g in plan.groups]
+
+    def fn(md_lo, md_hi, *flat):
+        groups = []
+        for k, g in enumerate(plan.groups):
+            words, starts, takes = flat[3 * k: 3 * k + 3]
+            groups.append((g[0], words,
+                           None if g[2] is None else starts,
+                           None if g[3] is None else takes,
+                           g[4], g[5], g[6]))
+        return expand_delta_i64(DeltaPlan(groups, md_lo, md_hi,
+                                          plan.block_size, plan.first,
+                                          plan.total))
+
+    args = [_spec(plan.md_lo, one_chip), _spec(plan.md_hi, one_chip)]
+    for words, starts, takes in host:
+        args.append(_spec(words, one_chip))
+        for a in (starts, takes):
+            args.append(_spec(np.zeros(1, np.int32) if a is None else a,
+                              one_chip))
+    _compile(fn, *args)
+
+
+def test_levels_to_validity_and_scatter(one_chip):
+    from tpuparquet.kernels.decode import (levels_to_validity,
+                                           scatter_to_dense)
+
+    lanes = 2
+    dl = jax.ShapeDtypeStruct((N,), jnp.int32, sharding=one_chip)
+    packed = jax.ShapeDtypeStruct((N * lanes,), jnp.uint32,
+                                  sharding=one_chip)
+
+    def fn(def_levels, packed):
+        mask, pos = levels_to_validity(def_levels, max_def=1)
+        return scatter_to_dense(packed, mask, pos, lanes=lanes)
+
+    _compile(fn, dl, packed)
+
+
+def test_expand_tokens(one_chip):
+    from tpuparquet.kernels.snappy import expand_tokens
+
+    n_tok = 1 << 16
+    te = jax.ShapeDtypeStruct((n_tok,), jnp.int32, sharding=one_chip)
+    ts = jax.ShapeDtypeStruct((n_tok,), jnp.int32, sharding=one_chip)
+    lits = jax.ShapeDtypeStruct((1 << 18,), jnp.uint8, sharding=one_chip)
+    _compile(expand_tokens, te, ts, lits, out_cap=N, steps=20)
+
+
+@pytest.mark.parametrize("width", [1, 17, 32])
+def test_unpack_u32_pallas_reaches_mosaic(one_chip, width):
+    """interpret=False must lower through Mosaic for the chip: a kernel
+    that quietly fell back to the interpreter would show no custom
+    call."""
+    from tpuparquet.kernels.bitunpack import unpack_u32_pallas
+
+    words = jax.ShapeDtypeStruct((N // 32, width), jnp.uint32,
+                                 sharding=one_chip)
+    text = jax.jit(unpack_u32_pallas,
+                   static_argnames=("width", "count", "interpret")).lower(
+        words, width=width, count=N, interpret=False).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def test_decode_step_spmd_all_gathers(topo):
+    """The SPMD decode step on four described chips: sharded unit-wise
+    over "rg", and the compiler must put in the all-gathers."""
+    from tpuparquet.shard.mesh import (decode_step_spmd, make_mesh,
+                                       stack_hybrid_plans)
+
+    mesh = make_mesh(devices=topo.devices)
+    assert mesh.devices.size == 4
+    plans = [_hybrid_plan(8, count=N // 8) for _ in range(8)]
+    batch = stack_hybrid_plans(plans, n_units=8)
+    lanes = 2
+    step = decode_step_spmd(mesh, batch.count, batch.width, batch.n_bp,
+                            lanes)
+    unit = NamedSharding(mesh, P("rg"))
+    rep = NamedSharding(mesh, P())
+    args = [_spec(a, unit) for a in batch.arrays()]
+    dictionary = jax.ShapeDtypeStruct((1 << 8, lanes), jnp.uint32,
+                                      sharding=rep)
+    compiled = step.lower(*args, dictionary).compile()
+    assert "all-gather" in compiled.as_text()
+    per_device = compiled.memory_analysis()
+    assert per_device is not None

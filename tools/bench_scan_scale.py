@@ -23,17 +23,15 @@ placed leg is parity-checked against the replicated values in-run.
 On virtual CPU devices every "device" is the same host, so absolute
 speedup is meaningless — what this measures is how the orchestration
 and the shipped volume scale with the mesh, which IS transferable to
-real chips (the phases are the same code).  ``tools/
-bench_opportunist.sh`` queues this sweep on the first healthy device
-window to capture the real-ICI curve.
+real chips (the phases are the same code).  The real-ICI curve needs
+a run with ``TPQ_SCAN_SCALE_BACKEND=device`` on a four-chip host.
 
     python tools/bench_scan_scale.py [out.json]
 
 Env: TPQ_SCAN_SCALE_UNITS (default 16), TPQ_SCAN_SCALE_VALUES
 (default 1_000_000 per unit), TPQ_SCAN_SCALE_REPS (default 3, first
 rep is compile warmup), TPQ_SCAN_SCALE_BACKEND=device to run on the
-real accelerator (default: the pinned virtual-8 CPU mesh; the
-opportunist loop passes device).
+real accelerator (default: the pinned virtual-8 CPU mesh).
 """
 
 import io

@@ -182,12 +182,16 @@ def cmd_meta(args, out=None) -> int:
     return rc
 
 
-def _report_findings(r, path: str, out) -> int:
+def _report_findings(r, path, out) -> int:
     """Run strict metadata validation on an open reader; print findings;
-    return 1 when any is an error."""
+    return 1 when any is an error.  ``path`` is a filesystem path or a
+    seekable file object (``cmd_verify`` takes either)."""
+    from ..format.footer import _file_size
     from ..format.validate import validate_metadata
 
-    findings = validate_metadata(r.metadata(), os.path.getsize(path))
+    size = (_file_size(path) if hasattr(path, "seek")
+            else os.path.getsize(path))
+    findings = validate_metadata(r.metadata(), size)
     for fd in findings:
         print(f"  {fd}", file=out)
     errors = sum(1 for fd in findings if fd.is_error)

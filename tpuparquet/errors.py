@@ -195,7 +195,7 @@ class DeadlineExceededError(_DeadlineInfo, TransientIOError):
 
 class DispatchDeadlineError(_DeadlineInfo, DeviceDispatchError):
     """A watched device dispatch ran past its time budget (wedged
-    accelerator, dead tunnel).  Subclasses
+    accelerator).  Subclasses
     :class:`DeviceDispatchError`, so the resilient read path's
     retry → CPU-fallback ladder handles it."""
 

@@ -212,14 +212,16 @@ def unpack_u32_pallas(words: jax.Array, width: int, count: int,
 
     Jitted so eager callers (and the A/B harness) don't pay a re-trace
     + re-lower of the pallas_call per invocation; inside the fused page
-    kernels the enclosing jit makes this a no-op."""
+    kernels the enclosing jit makes this a no-op.
+
+    ``interpret`` is the caller's choice: the default lowers through
+    Mosaic, which only a TPU target compiles; ``interpret=True`` runs
+    the Pallas interpreter on any backend (the CPU tests)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     if width == 0:
         return jnp.zeros((count,), dtype=jnp.uint32)
-    if not interpret and jax.default_backend() != "tpu":
-        interpret = True  # Mosaic only compiles for TPU
     if words.ndim == 1:
         words = words.reshape(-1, width)
     n_blocks = words.shape[0]

@@ -214,15 +214,23 @@ def scatter_to_dense(packed: jax.Array, mask: jax.Array,
     if lanes == 1:
         return jnp.where(mask, packed[positions],
                          jnp.zeros((), dtype=packed.dtype))
-    m = jnp.repeat(mask, lanes)
-    return jnp.where(m, packed[_flat_lane_indices(positions, lanes)],
-                     jnp.zeros((), dtype=packed.dtype))
+    rows = _take_rows(packed, positions, lanes)
+    return jnp.where(mask[:, None], rows,
+                     jnp.zeros((), dtype=packed.dtype)).reshape(-1)
 
 
-def _flat_lane_indices(idx, lanes: int):
-    """Value indices -> flat word indices in a (n*lanes,) lane buffer."""
-    return (idx[:, None] * lanes
-            + jnp.arange(lanes, dtype=idx.dtype)).reshape(-1)
+def _take_rows(flat, idx, lanes: int):
+    """Rows ``idx`` of a flat (n*lanes,) lane buffer viewed as
+    (n, lanes).  A row gather, not a gather of flat per-lane word
+    indices: at 1M values the TPU compiler (libtpu 0.0.34) took ~4
+    minutes on the flat-index form and under a second on this one, for
+    the same program and temp bytes (AOT compile for a described v5e,
+    PR 21).  Indices are in range by construction; ``clip`` is the
+    clamping a plain ``x[idx]`` gather does.  A bucket-padded buffer
+    may end in a partial row, which no index selects: it is dropped."""
+    whole = flat.shape[0] // lanes * lanes
+    return jnp.take(flat[:whole].reshape(-1, lanes), idx, axis=0,
+                    mode="clip")
 
 
 @functools.partial(jax.jit, static_argnames=("lanes",))
@@ -235,14 +243,13 @@ def dict_gather_fixed(dictionary: jax.Array, indices: jax.Array,
 def _dict_gather_flat(dictionary, indices, lanes: int):
     if lanes == 1:
         return dictionary[indices]
-    return dictionary[_flat_lane_indices(indices, lanes)]
+    return _take_rows(dictionary, indices, lanes).reshape(-1)
 
 
 # ----------------------------------------------------------------------
 # Fused per-page kernels: one dispatch per data page.  Decoding a page is
 # index-expand + gather (+ level expand); issuing them as one jit lets
-# XLA fuse everything and — more importantly on a remote-attached TPU —
-# collapses N dispatches into one.
+# XLA fuse everything and collapses N dispatches into one.
 # ----------------------------------------------------------------------
 
 def _expand_core(bp, ends, rle, val, start, cnt: int, w: int, nbp: int):
@@ -510,9 +517,10 @@ def _repeat_md(md_blocks, block_size: int, n_deltas: int) -> jax.Array:
     block ever cross the wire)."""
     mdb = jnp.asarray(md_blocks)
     n_blocks = mdb.shape[0]
-    return jnp.repeat(
-        mdb, block_size, total_repeat_length=n_blocks * block_size
-    )[:n_deltas]
+    # a broadcast, not jnp.repeat: same values, and the TPU compiler
+    # takes ~20 s on the repeat at 1M deltas (AOT compile, PR 21)
+    return jnp.broadcast_to(mdb[:, None], (n_blocks, block_size)
+                            ).reshape(-1)[:n_deltas]
 
 
 def expand_delta_i32(plan: DeltaPlan) -> jax.Array:

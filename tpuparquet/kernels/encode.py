@@ -29,16 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-# ``jax.enable_x64`` moved out of jax.experimental in newer releases;
-# older jaxlibs only ship the experimental spelling.  Same context
-# manager either way (both accept the bool flag).
-if hasattr(jax, "enable_x64"):
-    enable_x64 = jax.enable_x64
-else:  # pragma: no cover - depends on installed jax
-    from jax.experimental import enable_x64
-
 __all__ = [
-    "enable_x64",
     "pack_u32_device",
     "pack_u64_device",
     "bss_encode_device",
@@ -443,7 +434,7 @@ class DeviceValues:
         returns signed-storage values."""
         if self.count == 0:
             return None, None
-        with enable_x64(True):
+        with jax.enable_x64(True):
             v = self.flat
             if self.lanes == 2:
                 v = jax.lax.bitcast_convert_type(

@@ -12,6 +12,7 @@ available (``snappy_native() is None``).
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -29,6 +30,9 @@ _SRCS = [os.path.join(_DIR, "snappy.c"), os.path.join(_DIR, "hybrid.c"),
          os.path.join(_DIR, "pack.c"), os.path.join(_DIR, "intern.c"),
          os.path.join(_DIR, "page.c"), os.path.join(_DIR, "lz4raw.c")]
 _SO = os.path.join(_DIR, "_tpq_native.so")
+# content hash of the sources the .so was built from, kept beside it
+_STAMP = _SO + ".sha256"
+_CFLAGS = ["-O3", "-shared", "-fPIC"]
 
 _lock = threading.Lock()
 _cached: "ctypes.CDLL | None | bool" = False  # False = not tried yet
@@ -53,23 +57,41 @@ def hybrid_encode_cap(count: int, width: int) -> int:
     return groups * width + 5 * (groups + 2) + 32
 
 
+def _source_hash() -> str:
+    h = hashlib.sha256(" ".join(_CFLAGS).encode())
+    for src in _SRCS:
+        with open(src, "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()
+
+
 def _build() -> bool:
-    """(Re)build the shared library if stale; returns success."""
+    """(Re)build the shared library unless the stamp beside it names
+    the current sources' hash; returns success.  A hash, not mtimes: a
+    .so copied in from another machine or checkout is rebuilt unless it
+    was built from exactly these sources."""
     try:
-        if os.path.exists(_SO) and all(
-            os.path.getmtime(_SO) >= os.path.getmtime(src) for src in _SRCS
-        ):
-            return True
-        # per-process temp name: concurrent builders must not interleave
-        # writes into one file and then promote the garbage via replace
+        want = _source_hash()
+        try:
+            with open(_STAMP) as f:
+                if os.path.exists(_SO) and f.read().strip() == want:
+                    return True
+        except OSError:
+            pass
+        # per-process temp names: concurrent builders must not
+        # interleave writes into one file and then promote the garbage
+        # via replace
         tmp = f"{_SO}.{os.getpid()}.tmp"
         for cc in ("cc", "gcc", "clang"):
             try:
                 subprocess.run(
-                    [cc, "-O3", "-shared", "-fPIC", "-o", tmp, *_SRCS],
+                    [cc, *_CFLAGS, "-o", tmp, *_SRCS],
                     check=True, capture_output=True, timeout=120,
                 )
                 os.replace(tmp, _SO)
+                with open(f"{_STAMP}.{os.getpid()}.tmp", "w") as f:
+                    f.write(want)
+                os.replace(f"{_STAMP}.{os.getpid()}.tmp", _STAMP)
                 return True
             except (FileNotFoundError, subprocess.CalledProcessError,
                     subprocess.TimeoutExpired):

@@ -341,16 +341,10 @@ def decode_step_spmd(mesh: Mesh, count: int, width: int, n_bp: int,
 
     spec_unit = P("rg")
     in_specs = (spec_unit, spec_unit, spec_unit, spec_unit, spec_unit, P())
-    try:
-        # check_vma=False: the output *is* replicated (all-gathered over
-        # both axes) but the checker can't infer that through the gather.
-        sharded = jax.shard_map(step, mesh=mesh, in_specs=in_specs,
-                                out_specs=P(), check_vma=False)
-    except (AttributeError, TypeError):  # older jax
-        from jax.experimental.shard_map import shard_map
-
-        sharded = shard_map(step, mesh=mesh, in_specs=in_specs,
-                            out_specs=P(), check_rep=False)
+    # check_vma=False: the output *is* replicated (all-gathered over
+    # both axes) but the checker can't infer that through the gather.
+    sharded = jax.shard_map(step, mesh=mesh, in_specs=in_specs,
+                            out_specs=P(), check_vma=False)
     return jax.jit(sharded)
 
 
