@@ -289,6 +289,11 @@ def cmd_verify(args, out=None) -> int:
     return rc
 
 
+#: the device path's stages, in pipeline order: each a DecodeStats
+#: ``<stage>_s`` field and an event-log phase span of the same name
+_PHASES = ("plan", "plan_wait", "transfer", "dispatch", "drain")
+
+
 def profile_report(events, stats=None) -> dict:
     """Machine-readable profile digest: everything the human table
     prints, as one JSON-safe dict.  ``stats`` optional — a profile
@@ -313,8 +318,7 @@ def profile_report(events, stats=None) -> dict:
         rep["counters"] = d
         rep["histograms"] = stats.histograms_dict()
         rep["phases"] = {k: d[k] for k in
-                         ("plan_s", "transfer_s", "dispatch_s",
-                          "wall_s")}
+                         (*(p + "_s" for p in _PHASES), "wall_s")}
         # attribution view: per-stage cpu-seconds derived by the SAME
         # function the scan ledgers/doctor use (obs.stage_seconds), so
         # profile, top and doctor agree on numbers by construction
@@ -327,9 +331,8 @@ def profile_report(events, stats=None) -> dict:
     else:
         phases: dict = {}
         for s in events.spans:
-            key = {"plan": "plan_s", "transfer": "transfer_s",
-                   "dispatch": "dispatch_s"}.get(s.get("name"))
-            if key:
+            if s.get("name") in _PHASES:
+                key = s["name"] + "_s"
                 phases[key] = round(phases.get(key, 0.0) + s["dur"], 6)
         rep["phases"] = phases
     return rep
@@ -443,11 +446,10 @@ def _print_profile(log, st, out, trace_diag=None) -> None:
     print(obs.format_column_table(obs.column_table(log)), file=out)
     if st is not None:
         d = st.as_dict()
-        print(f"\nphases: plan {d['plan_s']:.3f}s  "
-              f"transfer {d['transfer_s']:.3f}s  "
-              f"dispatch {d['dispatch_s']:.3f}s  "
-              f"wall {d['wall_s']:.3f}s",
-              file=out)
+        print("\nphases: "
+              + "  ".join(f"{p.replace('_', ' ')} {d[p + '_s']:.3f}s"
+                          for p in _PHASES)
+              + f"  wall {d['wall_s']:.3f}s", file=out)
         # attribution section: the stage cpu_s view shared with the
         # scan ledgers / doctor (obs.stage_seconds)
         cpu = obs.stage_seconds(d)

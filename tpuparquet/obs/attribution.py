@@ -21,10 +21,11 @@ peak as max).
 
 **Span analysis** — the critical-path walk over a trace
 (:mod:`~tpuparquet.obs.trace`).  For every span, its *exclusive* time
-is its duration minus the union of its children's intervals; summing
-exclusive time by stage over a unit's subtree decomposes the unit
-wall exactly (buckets sum to the unit duration, gaps land in
-``driver``).  :func:`diagnose` folds that into the bound verdict
+is its duration minus the union of its children's intervals; summed
+by stage over a trace they give the scan's stage totals.  A unit's
+wall decomposes exactly: each instant goes to the deepest span of the
+unit's subtree running then (buckets sum to the unit duration even
+where column plans overlap; gaps land in ``driver``).  :func:`diagnose` folds that into the bound verdict
 (read-bound / plan-bound / decompress-bound / decode-bound /
 gather-bound), ranks straggler units against the rolling p95 of unit
 walls (:class:`~tpuparquet.deadline.LatencyTracker` — the same
@@ -49,30 +50,41 @@ __all__ = [
 #: span name -> canonical stage bucket
 STAGE_OF = {
     "read": "read", "read_replica": "read", "retry": "read",
-    "plan": "plan",
+    "plan": "plan", "plan_wait": "plan_wait",
     "decompress": "decompress",
     "transfer": "transfer", "stage": "transfer",
-    "dispatch": "dispatch",
+    "dispatch": "dispatch", "drain": "drain",
     "gather": "gather",
     "page_write": "write", "encode": "write", "compress": "write",
 }
 
-#: stage bucket -> doctor verdict (transfer and dispatch are both the
-#: decode side of the wall: bytes moving to, and kernels running on,
-#: the device)
+#: stage bucket -> doctor verdict (transfer, dispatch and drain are the
+#: decode side of the wall: bytes moving to, kernels enqueued on, and
+#: kernels finishing on the device; a consumer waiting on its plans is
+#: held by planning)
 VERDICT_OF = {
     "read": "read-bound", "plan": "plan-bound",
+    "plan_wait": "plan-bound",
     "decompress": "decompress-bound", "transfer": "decode-bound",
-    "dispatch": "decode-bound", "gather": "gather-bound",
+    "dispatch": "decode-bound", "drain": "decode-bound",
+    "gather": "gather-bound",
 }
+
+#: stage buckets of a consumer WAITING on work other threads do: the
+#: wait overlaps that work (the unit's plan spans on the pool), so in
+#: a unit's decomposition a wait keeps only the time no sibling span
+#: covers
+_WAITS = frozenset({"plan_wait"})
 
 #: DecodeStats counter -> stage, for the ledger/profile cpu_s view
 #: (decompress rides inside plan_s on the live pipeline — the plan
 #: phase decompresses page bodies; it stays a separate bucket only
-#: where a trace carries explicit decompress spans)
+#: where a trace carries explicit decompress spans).  plan_wait_s is
+#: not here: it is the consumer's wait on plan_s, not work of its own.
 _STAGE_COUNTERS = {
     "read": "read_s", "plan": "plan_s", "transfer": "transfer_s",
-    "dispatch": "dispatch_s", "gather": "gather_reshard_s",
+    "dispatch": "dispatch_s", "drain": "drain_s",
+    "gather": "gather_reshard_s",
 }
 
 
@@ -334,27 +346,41 @@ def exclusive_times(spans: list[dict]) -> dict:
     return out
 
 
-def _subtree_stages(root: dict, children: dict, excl: dict) -> dict:
-    """Exclusive-time-by-stage over one span's subtree.  The root's
-    own exclusive time lands in ``driver`` (loop bookkeeping, window
-    gaps) so the buckets always sum to the root's duration."""
-    stages: dict = {}
-    stack = [(root, True)]
+def _subtree_stages(root: dict, children: dict) -> dict:
+    """Wall-by-stage over one span's subtree: every instant of the
+    root's interval goes to one bucket — that of the deepest span of
+    the subtree running then, or ``driver`` (loop bookkeeping, window
+    gaps) where none runs — so the buckets always sum to the root's
+    duration, even where spans overlap (column plans on a pool).  A
+    wait (:data:`_WAITS`) ranks below every other span: a consumer
+    waiting on its unit's plan tasks counts only while none of them
+    runs."""
+    lo, hi = root["t0"], root["t0"] + root.get("dur", 0.0)
+    spans = []   # (start, end, rank, bucket), clipped to the root
+    stack = [(c, 1) for c in children.get(root["span"], ())]
     while stack:
-        s, is_root = stack.pop()
-        if is_root:
-            bucket = "driver"
-        elif s.get("status") == "cancelled":
+        s, depth = stack.pop()
+        if s.get("status") == "cancelled":
             # abandoned work (hedge losers, dropped pipeline units):
             # real seconds, but duplicate/discarded — kept out of the
             # stage buckets so it cannot tilt a bound verdict
             bucket = "cancelled"
         else:
             bucket = STAGE_OF.get(s.get("name"), "other")
-        stages[bucket] = stages.get(bucket, 0.0) + excl.get(s["span"],
-                                                           0.0)
-        for c in children.get(s["span"], ()):
-            stack.append((c, False))
+        a, b = max(s["t0"], lo), min(s["t0"] + s.get("dur", 0.0), hi)
+        if b > a:
+            spans.append((a, b, 0 if bucket in _WAITS else depth,
+                          bucket))
+        stack.extend((c, depth + 1) for c in children.get(s["span"], ()))
+    edges = sorted({lo, hi, *(a for a, *_ in spans),
+                    *(b for _, b, *_ in spans)})
+    stages: dict = {}
+    for x, y in zip(edges, edges[1:]):
+        rank, bucket = -1, "driver"
+        for a, b, r, bk in spans:
+            if a <= x and y <= b and r > rank:
+                rank, bucket = r, bk
+        stages[bucket] = stages.get(bucket, 0.0) + (y - x)
     return stages
 
 
@@ -376,12 +402,11 @@ def unit_reports(spans: list[dict]) -> list[dict]:
     with its wall, its stage buckets (summing to the wall), the stage
     that bounds it, and the coordinates of its largest child."""
     _, children, _ = span_tree(spans)
-    excl = exclusive_times(spans)
     rows = []
     for s in spans:
         if s.get("name") != "unit":
             continue
-        stages = _subtree_stages(s, children, excl)
+        stages = _subtree_stages(s, children)
         timed = {k: v for k, v in stages.items() if k in VERDICT_OF}
         bound = max(timed, key=timed.get) if timed else "driver"
         top = _top_child(s, children)

@@ -298,7 +298,9 @@ class Profiler:
                 offcpu = True
                 kind, site = wait
                 stack.append(f"[{kind}-wait {site}]")
-                if stage is None and kind == "io":
+                # a thread blocked on IO is reading, whatever stage
+                # encloses the read (chunk reads run inside plan)
+                if kind == "io":
                     stage = "read"
             if stage is None:
                 stage = "other"

@@ -40,6 +40,15 @@ high-resolution); the tracer keeps one ``(wall, perf)`` anchor pair so
 exports (:func:`~tpuparquet.obs.export.spans_otlp`) can map span
 starts back to epoch time.
 
+Stage boundaries of the unit pipeline (plan, plan wait, transfer,
+dispatch, drain) go through one call, :class:`stage`: a
+``jax.profiler.TraceAnnotation`` named ``tpq.<stage>`` (always — it
+lands on the device trace's clock whenever a profiler trace runs, and
+costs well under a microsecond when none does), the stage's
+``DecodeStats`` field, the span below when tracing is on, the event
+log's phase span when the collector keeps one, and the sampling
+profiler's stage mark when it is armed.
+
 Export: ``TPQ_TRACE_EXPORT`` names a file the scan drivers write at
 scan end (atomic tmp + replace) — ``*.perfetto.json`` /
 ``*.chrome.json`` → Chrome trace-event JSON (load at
@@ -54,12 +63,14 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import functools
 import itertools
 import os
 import threading
 import time
 from collections import deque
 
+from ..stats import current_stats
 from . import profiler as _profiler
 from .recorder import ThreadSlots
 
@@ -67,7 +78,8 @@ __all__ = [
     "Tracer", "tracer", "set_tracing", "trace_default",
     "sample_default", "trace_export_default", "current_ctx", "adopt",
     "start_trace", "end_trace", "open_span", "close_span",
-    "emit_span", "trace_scope", "snapshot_spans", "clear_spans",
+    "emit_span", "stage", "trace_scope", "snapshot_spans",
+    "clear_spans",
 ]
 
 #: Ambient (trace_id, span_id) of the innermost open span — the
@@ -373,6 +385,86 @@ def emit_span(name: str, t0: float, dur: float, *, status: str = "ok",
     if fields:
         rec.update(fields)
     tr.record(rec)
+
+
+@functools.cache
+def _annotation():
+    """``jax.profiler.TraceAnnotation``, imported on first use (the
+    tracer itself imports no JAX)."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation
+
+
+class stage:
+    """One stage of the unit pipeline, recorded once where it happens::
+
+        with stage("dispatch", "dispatch_s", column=path):
+            out[path] = finish(staged)
+
+    On entry: a ``TraceAnnotation("tpq.<name>", **fields)``; the
+    span ``name`` when tracing is on (pushed as the ambient context,
+    so spans opened inside nest under it; ``parent`` overrides the
+    ambient parent); the profiler's stage mark ``name`` when it is
+    armed.  On exit: the wall in seconds added to the current
+    collector's ``field``, and the thread CPU seconds
+    (``time.thread_time``) to its ``cpu`` field; an event-log phase
+    span when the collector keeps a log; the span closed, with status
+    ``"error"`` when the block raised.  :meth:`note` adds fields known
+    only at the end (span and event log; the annotation took its
+    fields on entry).  Never hold one across a ``yield``."""
+
+    __slots__ = ("name", "field", "cpu", "parent", "fields", "_ann",
+                 "_span", "_ptok", "_t0", "_c0")
+
+    def __init__(self, name: str, field: str | None = None, *,
+                 cpu: str | None = None, parent=None, **fields):
+        self.name = name
+        self.field = field
+        self.cpu = cpu
+        self.parent = parent
+        self.fields = fields
+
+    def note(self, **fields) -> None:
+        self.fields.update(fields)
+
+    def __enter__(self):
+        self._ann = _annotation()("tpq." + self.name, **self.fields)
+        self._ann.__enter__()
+        self._span = (open_span(self.name, parent=self.parent,
+                                **self.fields)
+                      if _active is not None else None)
+        self._ptok = (_profiler.stage_begin(self.name)
+                      if _profiler._active is not None else None)
+        # the CPU reading nests inside the wall reading, so cpu <= wall
+        self._t0 = time.perf_counter()
+        self._c0 = time.thread_time() if self.cpu is not None else 0.0
+        return self
+
+    def __exit__(self, et, ev, tb):
+        c1 = time.thread_time() if self.cpu is not None else 0.0
+        t1 = time.perf_counter()
+        try:
+            _profiler.stage_end(self._ptok)
+            if self._span is not None:
+                self._span["fields"] = self.fields
+                close_span(self._span,
+                           status="ok" if et is None else "error")
+            st = current_stats()
+            if st is not None:
+                if self.field is not None:
+                    setattr(st, self.field,
+                            getattr(st, self.field) + (t1 - self._t0))
+                if self.cpu is not None:
+                    setattr(st, self.cpu,
+                            getattr(st, self.cpu) + (c1 - self._c0))
+                if st.events is not None:
+                    st.events.span(self.name, "decode", self._t0, t1,
+                                   tid=threading.get_ident(),
+                                   **self.fields)
+        finally:
+            self._ann.__exit__(et, ev, tb)
+        return False
 
 
 @contextlib.contextmanager

@@ -87,6 +87,10 @@ class DecodeStats:
     # FLBA/boolean staging inside finish()) transfer outside the
     # stager and are not counted here.
     bytes_staged: int = 0
+    # arrays handed to jax.device_put by the batched stager (each split
+    # piece counts): with bytes_staged, whether staging is bound by
+    # bytes or by the number of puts
+    pieces_staged: int = 0
     # slow-path executions that a healthy build would run natively (e.g.
     # a stale .so forcing the numpy bp-stats fallback): nonzero means
     # perf has quietly regressed with no functional symptom
@@ -241,16 +245,23 @@ class DecodeStats:
     cache_hits_disk: int = 0
     cache_misses_disk: int = 0
     cache_evictions_disk: int = 0
-    # where the device-path wall went, accumulated per unit: host plan
-    # phase (page walk, decompression, run-table scans — overlapped with
-    # transfer by the pipelined reader, so plan_s can exceed the e2e
-    # wall), stager transfer (put(), blocking to completion), and
-    # dispatch+sync (finish ops + the batched block_until_ready).  On
-    # the real chip these tell which side binds: transfer_s ~ wall means
-    # the wire is the wall; plan_s ~ wall means the planner is.
+    # where the device-path wall went, each added by one
+    # obs.trace.stage where the work happens: host plan phase (page
+    # walk, decompression, run-table scans; wall summed over the plan
+    # pool's threads, so plan_s can exceed the e2e wall) and the CPU
+    # seconds of those threads inside it (plan_cpu_s <= plan_s; the
+    # rest is GIL, lock and I/O wait); the consumer's wait on the plan
+    # futures (plan_wait_s); stager transfer (put(), blocking to
+    # completion); dispatch, the enqueue of each column's page
+    # programs; and drain, the block_until_ready on the unit's
+    # buffers.  plan_wait/transfer/dispatch/drain run in turn on the
+    # consumer thread, so their sum is at most the consumer's wall.
     plan_s: float = 0.0
+    plan_cpu_s: float = 0.0
+    plan_wait_s: float = 0.0
     transfer_s: float = 0.0
     dispatch_s: float = 0.0
+    drain_s: float = 0.0
     wall_s: float = 0.0
     _t0: float = dataclasses.field(default=0.0, repr=False)
     # structured telemetry (tpuparquet/obs/): named log2-bucket
@@ -268,7 +279,7 @@ class DecodeStats:
         "pages_device_planes", "pages_device_delta_lanes",
         "pages_device_encoded", "pages_host_values", "values",
         "bytes_compressed", "bytes_uncompressed", "bytes_staged",
-        "bytes_read", "read_s",
+        "pieces_staged", "bytes_read", "read_s",
         "native_fallbacks", "pages_crc_verified", "crc_mismatches",
         "faults_injected", "io_retries", "dispatch_retries",
         "pages_degraded", "units_degraded", "units_quarantined",
@@ -289,7 +300,8 @@ class DecodeStats:
         "remote_retry",
         "cache_hits_mem", "cache_misses_mem", "cache_evictions_mem",
         "cache_hits_disk", "cache_misses_disk", "cache_evictions_disk",
-        "plan_s", "transfer_s", "dispatch_s",
+        "plan_s", "plan_cpu_s", "plan_wait_s", "transfer_s",
+        "dispatch_s", "drain_s",
     )
 
     def merge_from(self, other: "DecodeStats") -> None:
@@ -337,6 +349,7 @@ class DecodeStats:
             "bytes_compressed": self.bytes_compressed,
             "bytes_uncompressed": self.bytes_uncompressed,
             "bytes_staged": self.bytes_staged,
+            "pieces_staged": self.pieces_staged,
             "bytes_read": self.bytes_read,
             "read_s": round(self.read_s, 6),
             "native_fallbacks": self.native_fallbacks,
@@ -391,8 +404,11 @@ class DecodeStats:
             "cache_misses_disk": self.cache_misses_disk,
             "cache_evictions_disk": self.cache_evictions_disk,
             "plan_s": round(self.plan_s, 6),
+            "plan_cpu_s": round(self.plan_cpu_s, 6),
+            "plan_wait_s": round(self.plan_wait_s, 6),
             "transfer_s": round(self.transfer_s, 6),
             "dispatch_s": round(self.dispatch_s, 6),
+            "drain_s": round(self.drain_s, 6),
             "wall_s": round(self.wall_s, 6),
             "values_per_sec": round(self.values_per_sec, 1),
             "compression_ratio": round(self.compression_ratio, 3),
@@ -406,10 +422,13 @@ class DecodeStats:
             f"{d['bytes_compressed']:,}B -> {d['bytes_uncompressed']:,}B "
             f"(x{d['compression_ratio']}); "
             f"{d['wall_s']:.4f}s = {d['values_per_sec']:,.0f} values/s"
-            + (f"; staged {d['bytes_staged']:,}B to device"
+            + (f"; staged {d['bytes_staged']:,}B to device in "
+               f"{d['pieces_staged']:,} pieces"
                if d["bytes_staged"] else "")
-            + (f"; plan {d['plan_s']:.3f}s / transfer "
+            + (f"; plan {d['plan_s']:.3f}s (cpu {d['plan_cpu_s']:.3f}s)"
+               f" / plan wait {d['plan_wait_s']:.3f}s / transfer "
                f"{d['transfer_s']:.3f}s / dispatch {d['dispatch_s']:.3f}s"
+               f" / drain {d['drain_s']:.3f}s"
                if d["transfer_s"] else "")
             + (f"; {d['native_fallbacks']} native fallbacks (stale .so?)"
                if d["native_fallbacks"] else "")
