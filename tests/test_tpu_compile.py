@@ -148,6 +148,45 @@ def test_levels_to_validity_and_scatter(one_chip):
     _compile(fn, dl, packed)
 
 
+@pytest.mark.parametrize("kind", ["dict", "dict_bytes", "plain"])
+def test_chunk_program(one_chip, kind):
+    """One column chunk of a 1,048,576-row row group in one program:
+    52 pages of 20,000 rows (a group of 56 slots) and a short last
+    page, nullable, as a TLC taxi month's chunks are."""
+    from tpuparquet.kernels.decode import chunk_program
+
+    u32 = jnp.uint32
+
+    def arr(shape, dtype=u32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def stream(cnt, w, runs):
+        return arr((cnt // 32 * w,)), arr((4, runs))
+
+    lev = (tuple(stream(32768, 1, 32) for _ in range(56)),
+           (stream(16384, 1, 32),))
+    lev_sig = ((32768, 1, 32768, True), (16384, 1, 16384, True))
+    lanes, shared = 2, (arr((1024,)),)
+    if kind == "dict":
+        val = (tuple(stream(32768, 9, 64) for _ in range(56)),
+               (stream(16384, 9, 64),))
+        val_sig = (("dict", 32768, 9, 32768, False),
+                   ("dict", 16384, 9, 16384, False))
+    elif kind == "dict_bytes":
+        lanes, shared = 1, (arr((32,), jnp.int32), arr((32,), jnp.uint8))
+        val = (tuple(stream(32768, 1, 32) for _ in range(56)),
+               (stream(16384, 1, 32),))
+        val_sig = (("dict_bytes", 32768, 1, 32768, True, 32768),
+                   ("dict_bytes", 16384, 1, 16384, True, 16384))
+    else:
+        val = (tuple((arr((40960,)),) for _ in range(56)),
+               ((arr((32768,)),),))
+        val_sig = (("plain",), ("plain",))
+    meta = arr((114, 3), jnp.int32)
+    sig = (lev_sig, val_sig, N, N * lanes, lanes, 1)
+    _compile(chunk_program, shared, lev, val, meta, sig=sig)
+
+
 def test_expand_tokens(one_chip):
     from tpuparquet.kernels.snappy import expand_tokens
 

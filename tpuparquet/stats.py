@@ -91,6 +91,12 @@ class DecodeStats:
     # piece counts): with bytes_staged, whether staging is bound by
     # bytes or by the number of puts
     pieces_staged: int = 0
+    # device programs each column's finish() enqueued: one per chunk
+    # decoded by a chunk program (chunks_fused counts those chunks);
+    # on the per-page path each page kernel, slice, concatenate and
+    # validity program
+    chunks_fused: int = 0
+    programs_dispatched: int = 0
     # slow-path executions that a healthy build would run natively (e.g.
     # a stale .so forcing the numpy bp-stats fallback): nonzero means
     # perf has quietly regressed with no functional symptom
@@ -279,7 +285,8 @@ class DecodeStats:
         "pages_device_planes", "pages_device_delta_lanes",
         "pages_device_encoded", "pages_host_values", "values",
         "bytes_compressed", "bytes_uncompressed", "bytes_staged",
-        "pieces_staged", "bytes_read", "read_s",
+        "pieces_staged", "chunks_fused", "programs_dispatched",
+        "bytes_read", "read_s",
         "native_fallbacks", "pages_crc_verified", "crc_mismatches",
         "faults_injected", "io_retries", "dispatch_retries",
         "pages_degraded", "units_degraded", "units_quarantined",
@@ -350,6 +357,8 @@ class DecodeStats:
             "bytes_uncompressed": self.bytes_uncompressed,
             "bytes_staged": self.bytes_staged,
             "pieces_staged": self.pieces_staged,
+            "chunks_fused": self.chunks_fused,
+            "programs_dispatched": self.programs_dispatched,
             "bytes_read": self.bytes_read,
             "read_s": round(self.read_s, 6),
             "native_fallbacks": self.native_fallbacks,
@@ -428,6 +437,8 @@ class DecodeStats:
             + (f"; plan {d['plan_s']:.3f}s (cpu {d['plan_cpu_s']:.3f}s)"
                f" / plan wait {d['plan_wait_s']:.3f}s / transfer "
                f"{d['transfer_s']:.3f}s / dispatch {d['dispatch_s']:.3f}s"
+               f" ({d['programs_dispatched']:,} programs, "
+               f"{d['chunks_fused']}/{d['chunks']} chunks fused)"
                f" / drain {d['drain_s']:.3f}s"
                if d["transfer_s"] else "")
             + (f"; {d['native_fallbacks']} native fallbacks (stale .so?)"
