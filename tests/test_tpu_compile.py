@@ -182,9 +182,53 @@ def test_chunk_program(one_chip, kind):
         val = (tuple((arr((40960,)),) for _ in range(56)),
                ((arr((32768,)),),))
         val_sig = (("plain",), ("plain",))
-    meta = arr((114, 3), jnp.int32)
+    meta = arr((114, 5), jnp.int32)
     sig = (lev_sig, val_sig, N, N * lanes, lanes, 1)
     _compile(chunk_program, shared, lev, val, meta, sig=sig)
+
+
+@pytest.mark.parametrize("kind", ["plain_bytes", "planes", "delta"])
+def test_chunk_program_lineitem(one_chip, kind):
+    """A required TPC-H lineitem chunk of a 1,048,576-row row group:
+    pages of 20,000 rows after a dictionary fills, as ``l_comment``
+    (PLAIN bytes), ``l_partkey`` (byte planes) and ``l_orderkey``
+    (delta lanes) write them."""
+    from tpuparquet.kernels.decode import chunk_program
+
+    u32, u8, i32 = jnp.uint32, jnp.uint8, jnp.int32
+
+    def arr(shape, dtype=u32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    lanes, shared = 2, ()
+    if kind == "plain_bytes":
+        lanes = 1
+        shared = (arr((32768,), i32), arr((1 << 20,), u8))
+        val = ((((arr((4096 * 17 // 32,)), arr((4, 32))),) * 2),
+               ((arr((540672,), u8),),) * 56, ((arr((139264,), u8),),))
+        val_sig = (("dict_bytes", 4096, 17, 4096, True, 1 << 17),
+                   ("plain_bytes",), ("plain_bytes",))
+        total = 1 << 25
+    elif kind == "planes":
+        spec = (("bytes", ("raw8", 0), ("raw8", 1), ("raw8", 2),
+                 ("rle8", 0, 32)), ("rle32", 0, 32))
+
+        def planes(stride):
+            return (arr((1,)), arr((32,), i32), arr((32,)),
+                    arr((3 * stride,), u8), arr((32,), i32),
+                    arr((32,), u8))
+
+        val = ((planes(20000),) * 48, (planes(8576),))
+        val_sig = (("planes", spec, 20000), ("planes", spec, 8576))
+        total = N * lanes
+    else:
+        delta = (arr((32768 // 32 * 5,)), arr((1,)), arr((1,)))
+        val = ((delta,) * 32, (delta,))
+        val_sig = (("delta", 32768, 5, True), ("delta", 32768, 4, True))
+        total = N * lanes
+    meta = arr((sum(len(g) for g in val), 5), i32)
+    sig = ((), val_sig, 0, total, lanes, 0)
+    _compile(chunk_program, shared, (), val, meta, sig=sig)
 
 
 def test_expand_tokens(one_chip):

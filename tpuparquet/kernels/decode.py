@@ -123,11 +123,12 @@ def _rle_expand(ends: jax.Array, vals: jax.Array, start: int, n_runs: int,
     return vals[start + idx]
 
 
-@functools.partial(jax.jit, static_argnames=("spec", "count", "lanes"))
+@functools.partial(jax.jit, static_argnames=("spec", "count", "lanes",
+                                             "stride"))
 def planes_to_words(raw32: jax.Array, rle32_ends: jax.Array,
                     rle32_vals: jax.Array, raw8: jax.Array,
                     rle8_ends: jax.Array, rle8_vals: jax.Array,
-                    spec: tuple, count: int, lanes: int):
+                    spec: tuple, count: int, lanes: int, stride: int):
     """Lane/byte-plane wire transport -> flat u32 lane words.
 
     The host ships each of the value's u32 lanes one of three ways —
@@ -136,15 +137,22 @@ def planes_to_words(raw32: jax.Array, rle32_ends: jax.Array,
     descended to its four byte planes (``("bytes", e0, e1, e2, e3)``
     with per-plane ``("raw8", slab)`` / ``("rle8", start, n_runs)``
     entries: catches constant upper bytes INSIDE an otherwise-random
-    lane, e.g. values < 2^16 in an int64).  Only genuinely random bytes
-    pay full wire; reconstruction (searchsorted expands + shift
-    combine) is pure parallel device work."""
+    lane, e.g. values < 2^16 in an int64).  Raw slab ``j`` starts at
+    ``j * stride``.  Only genuinely random bytes pay full wire;
+    reconstruction (searchsorted expands + shift combine) is pure
+    parallel device work."""
+    return _planes_words(raw32, rle32_ends, rle32_vals, raw8, rle8_ends,
+                         rle8_vals, spec, count, lanes, stride)
+
+
+def _planes_words(raw32, rle32_ends, rle32_vals, raw8, rle8_ends,
+                  rle8_vals, spec, count: int, lanes: int, stride: int):
     words = []
     for entry in spec:
         kind = entry[0]
         if kind == "raw32":
             j = entry[1]
-            words.append(raw32[j * count : (j + 1) * count])
+            words.append(raw32[j * stride : j * stride + count])
         elif kind == "rle32":
             words.append(_rle_expand(rle32_ends, rle32_vals,
                                      entry[1], entry[2], count))
@@ -153,7 +161,7 @@ def planes_to_words(raw32: jax.Array, rle32_ends: jax.Array,
             for sub in entry[1:]:
                 if sub[0] == "raw8":
                     j = sub[1]
-                    b.append(raw8[j * count : (j + 1) * count]
+                    b.append(raw8[j * stride : j * stride + count]
                              .astype(jnp.uint32))
                 else:
                     b.append(_rle_expand(rle8_ends, rle8_vals,
@@ -434,13 +442,22 @@ def _expand_pages(bp, tbl, cnt: int, w: int, nbp: int, single: bool):
                                                 nbp), (bp, tbl))
 
 
-def _chunk_values(kind, statics, group, shared, nn, lanes):
+def _chunk_values(kind, statics, group, shared, rows, lanes):
     """One value group -> ``(pages, width)``, in output units."""
-    if kind == "plain":
+    if kind in ("plain", "plain_bytes"):
         return _stack(group, 0)
+    arrs = tuple(_stack(group, k) for k in range(len(group[0])))
+    if kind == "planes":
+        spec, stride = statics
+        return jax.lax.map(lambda page: _planes_words(
+            *page, spec, stride, lanes, stride), arrs)
+    if kind == "delta":
+        first = jax.lax.bitcast_convert_type(rows[:, 3:5], jnp.uint32)
+        return jax.lax.map(lambda page: _delta_page(
+            page[0], page[1], *statics), (arrs, first))
     icnt, iw, inbp, isingle = statics[:4]
-    idx = _expand_pages(_stack(group, 0), _stack(group, 1), icnt, iw,
-                        inbp, isingle).astype(jnp.int32)
+    idx = _expand_pages(arrs[0], arrs[1], icnt, iw, inbp,
+                        isingle).astype(jnp.int32)
     if kind == "dict":
         # one 1-D gather per lane, interleaved per page: a row gather
         # (_take_rows) of a whole chunk's indices took the TPU compiler
@@ -451,7 +468,38 @@ def _chunk_values(kind, statics, group, shared, nn, lanes):
         words = [dictionary[k::lanes][idx] for k in range(lanes)]
         return jnp.stack(words, axis=-1).reshape(idx.shape[0], -1)
     return jax.lax.map(lambda page: _dict_bytes_gather(
-        shared[0], shared[1], page[0], page[1], statics[4]), (idx, nn))
+        shared[0], shared[1], page[0], page[1], statics[4]),
+        (idx, rows[:, 2]))
+
+
+def _delta_page(arrs, first, n_vals: int, w: int, wide: bool):
+    """One page of the delta-lane transport -> its values as flat u32
+    lanes, ``n_vals + 1`` of them, of which the page's own count lead.
+    ``arrs``: the packed words of the one width class (none at width
+    0), then the page's min_delta as one-value lo (and, for 64 bits,
+    hi) arrays; ``first``: the first value's (lo, hi) words.  The same
+    arithmetic as :func:`expand_delta_i32` / :func:`expand_delta_i64`
+    on a plan with one contiguous group, with the count and the first
+    value as runtime data."""
+    words = arrs[0][: n_vals // 32 * w] if w else None
+    md = arrs[1:] if w else arrs
+    if not wide:
+        d = (unpack_u32(words, w, n_vals) if w
+             else jnp.zeros((n_vals,), jnp.uint32))
+        full = d + md[0][0]  # u32 wraparound == two's complement
+        return jnp.concatenate([first[:1],
+                                first[0] + _running_count(full)])
+    from .bitunpack import unpack_u64
+
+    if w:
+        lo, hi = unpack_u64(words, w, n_vals)
+    else:
+        lo = hi = jnp.zeros((n_vals,), jnp.uint32)
+    flo, fhi = _add64((lo, hi), (md[0][0], md[1][0]))
+    lo, hi = jax.lax.associative_scan(
+        _add64, (jnp.concatenate([first[:1], flo]),
+                 jnp.concatenate([first[1:], fhi])))
+    return jnp.stack([lo, hi], axis=1).reshape(-1)
 
 
 @functools.partial(jax.jit, static_argnames=("sig",))
@@ -460,15 +508,20 @@ def chunk_program(shared, lev_groups, val_groups, meta, sig):
 
     ``sig`` = ``(lev_sig, val_sig, lev_len, val_len, lanes, max_def)``:
     per level group its ``(cnt, w, nbp, single)``; per value group its
-    kind (``"dict"``, ``"plain"``, ``"dict_bytes"``) and statics
-    (``(icnt, iw, inbp, isingle)``, plus the byte ``cap`` for
-    ``"dict_bytes"``); the bucketed level and value totals; u32 lanes
-    per value; the column's max definition level.  ``lev_groups`` and
-    ``val_groups`` hold each group's pages' staged arrays (level and
-    index streams as ``(bp_words, table)``, PLAIN values as
-    ``(words,)``); ``shared`` the chunk's dictionary arrays.  ``meta``
-    has one int32 row ``(offset, length, non_null)`` per page slot,
-    level groups' slots first, then the value groups', in group order.
+    kind and statics: ``"dict"`` and ``"dict_bytes"`` the indices'
+    ``(icnt, iw, inbp, isingle)``, plus the byte ``cap`` for
+    ``"dict_bytes"``; ``"plain"`` and ``"plain_bytes"`` none;
+    ``"planes"`` the lane ``spec`` and slab ``stride``
+    (:func:`planes_to_words`); ``"delta"`` ``(n_vals, w, wide)``
+    (:func:`_delta_page`).  Then the bucketed level and value totals;
+    u32 lanes per value; the column's max definition level.
+    ``lev_groups`` and ``val_groups`` hold each group's pages' staged
+    arrays (level and index streams as ``(bp_words, table)``, PLAIN
+    values and bytes as ``(words,)``, the transports' as they are
+    staged); ``shared`` the chunk's dictionary arrays.  ``meta`` has
+    one int32 row ``(offset, length, non_null, aux0, aux1)`` per page
+    slot, level groups' slots first, then the value groups', in group
+    order; ``aux`` holds a delta page's first value as (lo, hi) words.
 
     Returns bucket-padded ``(def_levels, values, mask, positions)``;
     levels, mask and positions are None for a required column."""
@@ -481,8 +534,8 @@ def chunk_program(shared, lev_groups, val_groups, meta, sig):
         slot += len(group)
     for (kind, *statics), group in zip(val_sig, val_groups):
         rows = meta[slot : slot + len(group)]
-        vals.append((_chunk_values(kind, statics, group, shared,
-                                   rows[:, 2], lanes), rows))
+        vals.append((_chunk_values(kind, statics, group, shared, rows,
+                                   lanes), rows))
         slot += len(group)
     if not lev:
         return None, _assemble(vals, val_len), None, None

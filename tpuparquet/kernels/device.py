@@ -33,6 +33,7 @@ import contextlib
 import os
 import threading
 import time
+from typing import NamedTuple
 
 import numpy as np
 
@@ -160,24 +161,9 @@ def _DEVICE_DELTA_LANES() -> bool:
 
 
 def _padded_u32_bytes(n_words: int) -> int:
-    """POST-split staged bytes of an (n_words,) u32 array — the pure
-    arithmetic of ``_split_rows``' decomposition (16 MB pieces, then
-    descending powers of two down to ``_MIN_PIECE_BYTES``, then one
-    bucketed tail), so wire estimates don't materialize throwaway
-    arrays."""
-    from .decode import bucket
-
-    max_rows = 1 << ((_PIECE_BYTES // 4).bit_length() - 1)
-    min_rows = 1 << ((_MIN_PIECE_BYTES // 4).bit_length() - 1)
-    total = (n_words // max_rows) * max_rows
-    left = n_words - total
-    while left >= min_rows:
-        p = 1 << (left.bit_length() - 1)
-        total += p
-        left -= p
-    if left:
-        total += bucket(left)
-    return total * 4
+    """POST-split staged bytes of an (n_words,) u32 array, so wire
+    estimates don't materialize throwaway arrays."""
+    return sum(_piece_rows(n_words, 4)) * 4
 
 
 def _plan_delta_lane_words(seg, count: int, ptype: Type, params=None):
@@ -205,7 +191,8 @@ def _plan_delta_lane_words(seg, count: int, ptype: Type, params=None):
     expand kernels' lane adds and prefix scan — random pages reject on
     width, never corrupt.  Returns (exact_wire_bytes, commit) or None;
     ``commit(stager)`` stages the plan and returns ``get_words(staged)``
-    producing the flat u32 lane layout PLAIN consumers slice."""
+    producing the flat u32 lane layout PLAIN consumers slice, and the
+    page's ``(kind, handles, statics, aux)`` for a :class:`_PageOp`."""
     from ..native import pack_native
 
     if count < 1024 or pack_native() is None:
@@ -304,9 +291,9 @@ def _plan_delta_lane_words(seg, count: int, ptype: Type, params=None):
         md_hi = np.asarray([md_u >> np.uint64(32)], dtype=np.uint32)
         groups = ([(w, words, None, None, n_pad, 0, n_deltas)]
                   if w else [])
-        plan = DeltaPlan(groups, md_lo, md_hi, n_deltas, int(v[0]),
-                         count)
-        build = _stage_delta_plan(plan, stager, need_hi=_i64)
+        first = int(v[0])
+        plan = DeltaPlan(groups, md_lo, md_hi, n_deltas, first, count)
+        build, hs = _stage_delta_plan(plan, stager, need_hi=_i64)
 
         def get_words(s, _b=build):
             from .decode import expand_delta_i32, expand_delta_i64
@@ -314,7 +301,8 @@ def _plan_delta_lane_words(seg, count: int, ptype: Type, params=None):
             return (expand_delta_i64(_b(s)) if _i64
                     else expand_delta_i32(_b(s)))
 
-        return get_words
+        return get_words, ("delta", hs, (n_pad, w, _i64),
+                           (first & 0xFFFFFFFF, first >> 32))
 
     return wire, commit, (md, w)
 
@@ -446,9 +434,15 @@ def _plan_plane_words(seg, count: int, lanes: int, stager: "_Stager",
     still re-checks what the BUILT tables weigh, so a stale hint ships
     raw rather than a losing transport.
 
-    Returns ``(wire, words_closure, lane_plans)`` — the wire cost
+    Each raw slab is staged ``stride`` values long, the count rounded up
+    to a multiple of 32, so pages whose counts differ by less than that
+    share a chunk program's group.
+
+    Returns ``(wire, words_closure, lane_plans, val)`` — the wire cost
     recomputed from the BUILT tables (what the gate actually accepted;
-    the event log reports it) — or None when the page rejects."""
+    the event log reports it), and ``val``, the page's ``(kind, handles,
+    statics, aux)`` for a :class:`_PageOp` — or None when the page
+    rejects."""
     from .decode import bucket
 
     if count < 1024:
@@ -499,16 +493,25 @@ def _plan_plane_words(seg, count: int, lanes: int, stager: "_Stager",
     s32 = s8 = 0
     spec = []
     actual = 0  # wire recomputed from BUILT tables (samples can lie)
+    # each raw slab stages a multiple of 32 values long
+    stride = -(-count // 32) * 32
+
+    def slab(col):
+        if stride == count:
+            return col
+        out = np.zeros(stride, col.dtype)
+        out[:count] = col
+        return out
 
     def raw32(lane_v):
         nonlocal actual
         spec.append(("raw32", len(raw32_parts)))
-        raw32_parts.append(_lane_contig(lane_v))
+        raw32_parts.append(slab(_lane_contig(lane_v)))
         actual += 4 * count
 
     def raw8(col):
         nonlocal actual
-        raw8_parts.append(col)
+        raw8_parts.append(slab(col))
         actual += count
         return ("raw8", len(raw8_parts) - 1)
 
@@ -576,15 +579,17 @@ def _plan_plane_words(seg, count: int, lanes: int, stager: "_Stager",
         pad=False)
     spec = tuple(spec)
 
-    def words(staged, _hs=hs, _spec=spec, _count=count, _lanes=lanes):
+    def words(staged, _hs=hs, _spec=spec, _count=count, _lanes=lanes,
+              _stride=stride):
         from .decode import planes_to_words
 
         return planes_to_words(
             staged[_hs[0]], staged[_hs[1]], staged[_hs[2]],
             staged[_hs[3]], staged[_hs[4]], staged[_hs[5]],
-            _spec, _count, _lanes)
+            _spec, _count, _lanes, _stride)
 
-    return actual, words, plans
+    return (actual, words, plans,
+            ("planes", tuple(hs), (spec, stride), (0, 0)))
 
 
 def _stage_delta_plan(plan, stager: "_Stager", need_hi: bool):
@@ -597,10 +602,13 @@ def _stage_delta_plan(plan, stager: "_Stager", need_hi: bool):
     scatter starts/takes and the per-block min_delta lanes ship exact —
     padding would corrupt scatter targets and the repeat length.
     ``need_hi`` is False for i32 plans: ``expand_delta_i32`` never
-    reads the hi lane, so it stays host-side."""
+    reads the hi lane, so it stays host-side.  Returns the build and
+    the staged handles: each group's words (and its starts and takes),
+    then the min_delta lanes staged."""
     from .decode import DeltaPlan
 
     specs = []
+    h0 = len(stager.arrays)
     for w, words, starts, takes, n_vals, start, n_take in plan.groups:
         wh = stager.add(words)
         if starts is None:
@@ -614,6 +622,7 @@ def _stage_delta_plan(plan, stager: "_Stager", need_hi: bool):
     lo_h = stager.add(plan.md_lo, pad=False) if has_md else None
     hi_h = stager.add(plan.md_hi, pad=False) if has_md and need_hi \
         else None
+    hs = tuple(range(h0, len(stager.arrays)))
     # captured by value: holding the plan object itself would keep the
     # just-staged host words/starts/takes arrays alive through dispatch
     lo_host = None if has_md else plan.md_lo
@@ -637,7 +646,7 @@ def _stage_delta_plan(plan, stager: "_Stager", need_hi: bool):
             *_meta,
         )
 
-    return build
+    return build, hs
 
 
 def _plan_device_snappy_words(payload, expected_size: int, n_words: int,
@@ -990,47 +999,59 @@ _MIN_PIECE_BYTES = 128 << 10
 _WAVE_BYTES = 96 << 20    # max bytes in flight per wave
 
 
+def _piece_rows(n: int, row_bytes: int) -> list[int]:
+    """Row counts of the pieces :func:`_split_rows` cuts an ``n``-row
+    array into: 16 MB pieces, then descending powers of two down to
+    ``_MIN_PIECE_BYTES``, then one tail bucket of at most
+    ``_MIN_PIECE_BYTES`` (the only piece that holds zero padding)."""
+    from .decode import bucket
+
+    max_rows = max(1, 1 << max(0, (_PIECE_BYTES // row_bytes)
+                               .bit_length() - 1))
+    min_rows = max(1, 1 << max(0, (_MIN_PIECE_BYTES // row_bytes)
+                               .bit_length() - 1))
+    rows = [max_rows] * (n // max_rows)
+    left = n - len(rows) * max_rows
+    while left >= min_rows:
+        p = 1 << (left.bit_length() - 1)
+        rows.append(p)
+        left -= p
+    if left:
+        rows.append(bucket(left))  # <= min_rows (bucket() floors at 32)
+    return rows
+
+
 def _split_rows(a: np.ndarray):
     """Decompose an array into leading-dim pieces with power-of-two row
     counts (descending), zero-padding only the final piece.  Keeps the
     universe of transferred shapes small — each distinct (shape, dtype)
     costs a one-time transfer-program compile — without bucket-padding
-    whole multi-hundred-MB buffers."""
+    whole multi-hundred-MB buffers.  Zero-copy slices but for the
+    tail: the host copies at most _MIN_PIECE_BYTES per array, and the
+    reassembled total is deterministic in n (bounded jit keys)."""
     if a.ndim == 0 or a.shape[0] == 0:
         return [a]
-    from .decode import bucket
-
-    row_bytes = a.itemsize
-    for d in a.shape[1:]:
-        row_bytes *= d
-    max_rows = max(1, 1 << max(0, (_PIECE_BYTES // row_bytes)
-                               .bit_length() - 1))
-    min_rows = max(1, 1 << max(0, (_MIN_PIECE_BYTES // row_bytes)
-                               .bit_length() - 1))
-    # Zero-copy slices with power-of-two row counts: 16 MB pieces, then
-    # descending powers of two down to _MIN_PIECE_BYTES, then one
-    # zero-padded tail of at most _MIN_PIECE_BYTES.  Transfer-program
-    # shapes stay a small power-of-two universe, the host copies at
-    # most _MIN_PIECE_BYTES per array, and the reassembled total is
-    # deterministic in n (bounded jit keys).
     n = a.shape[0]
     pieces = []
     pos = 0
-    while n - pos >= max_rows:
-        pieces.append(a[pos : pos + max_rows])
-        pos += max_rows
-    left = n - pos
-    while left >= min_rows:
-        p = 1 << (left.bit_length() - 1)
-        pieces.append(a[pos : pos + p])
-        pos += p
-        left -= p
-    if left:
-        b = bucket(left)  # <= min_rows (bucket() floors at 32)
-        tail = np.zeros((b,) + a.shape[1:], a.dtype)
-        tail[:left] = a[pos:]
-        pieces.append(tail)
+    for rows in _piece_rows(n, a.nbytes // n):
+        if pos + rows <= n:
+            pieces.append(a[pos : pos + rows])
+        else:
+            tail = np.zeros((rows,) + a.shape[1:], a.dtype)
+            tail[: n - pos] = a[pos:]
+            pieces.append(tail)
+        pos += rows
     return pieces
+
+
+def _staged_shape(stager: "_Stager", h: int) -> tuple:
+    """The shape ``stager.put()`` gives array ``h`` on the device."""
+    a = stager.arrays[h]
+    if h in stager.no_pad or a.ndim == 0 or a.shape[0] == 0:
+        return a.shape
+    return (sum(_piece_rows(a.shape[0], a.nbytes // a.shape[0])),) \
+        + a.shape[1:]
 
 
 class _Stager:
@@ -1477,6 +1498,7 @@ def plan_chunk_device(blob, cm: ColumnMetaData, node: SchemaNode,
         # the scan outright — trading a few percent of wire precision
         # in the crossover region for ~30% of the plan phase.
         plan_words = None
+        fused_val = None  # a planes or delta page's _PageOp value kernel
         payload_bound = None
         # cached verdict for a PLAIN fixed-width page: run ONLY the
         # remembered winner's planner (or none, for a raw page) — the
@@ -1528,7 +1550,7 @@ def plan_chunk_device(blob, cm: ColumnMetaData, node: SchemaNode,
         planes_wire = None
         _pl = _try_planes(min(budgets) if budgets else None)
         if _pl is not None:
-            planes_wire, plan_words, planes_spec = _pl
+            planes_wire, plan_words, planes_spec, fused_val = _pl
         chosen = "planes" if plan_words is not None else None
         tok = None
         tok_scanned = False
@@ -1551,12 +1573,12 @@ def plan_chunk_device(blob, cm: ColumnMetaData, node: SchemaNode,
                     # have been pruned ONLY by it)
                     _pl = _try_planes(delta_wire)
                     if _pl is not None:
-                        planes_wire, plan_words, planes_spec = _pl
+                        planes_wire, plan_words, planes_spec, fused_val = _pl
                     chosen = "planes" if plan_words is not None else None
             if plan_words is None:
                 if delta_cand is not None and (
                         tok is None or delta_cand[0] < tok[0]):
-                    plan_words = delta_cand[1](stager)
+                    plan_words, fused_val = delta_cand[1](stager)
                     chosen = "delta"
                 elif tok is not None:
                     plan_words = tok[1](stager)
@@ -1816,7 +1838,6 @@ def plan_chunk_device(blob, cm: ColumnMetaData, node: SchemaNode,
                 raise ValueError("dict-encoded page without dictionary")
         elif enc == Encoding.PLAIN:
             if ptype == Type.BYTE_ARRAY:
-                _def_standalone()
                 col = decode_plain(ptype, values_seg, non_null)  # host scan
                 offs = col.offsets.astype(np.int32)
                 from .decode import bucket as _bucket
@@ -1849,6 +1870,7 @@ def plan_chunk_device(blob, cm: ColumnMetaData, node: SchemaNode,
                     # (length prefixes skipped arithmetically)
                     from .decode import bucket, plain_bytes_from_blob
 
+                    _def_standalone()
                     blob_wire, blob_plan = blob_plan
                     _tr = "snappy-tokens"
                     _wire_ev = blob_wire
@@ -1875,14 +1897,15 @@ def plan_chunk_device(blob, cm: ColumnMetaData, node: SchemaNode,
 
                     ops.append(op)
                 else:
+                    # the bytes stage padded to a bucket, so pages of
+                    # other exact lengths share a chunk program's group
                     _tr = "raw"
                     _wire_ev = _raw_ev
-                    dh = stager.add(col.data)
-                    ops.append(
-                        lambda s, p, _dh=dh, _o=offs,
-                        _nb=int(col.data.size):
-                        p["bytes"].append((_o, s[_dh], _nb))
-                    )
+                    ops.append(_PageOp(
+                        dl_ref, dwidth, "plain_bytes",
+                        (stager.add(col.data),), (), (), None, n,
+                        non_null, offsets=offs,
+                        nbytes=int(col.data.size)))
             elif ptype in _LANES:
                 # zero-copy u32 view of the decompressed values rides the
                 # one batched transfer (or the words come straight from
@@ -1893,27 +1916,12 @@ def plan_chunk_device(blob, cm: ColumnMetaData, node: SchemaNode,
                     wh = stager.add(stage_u32(values_seg, non_null * lanes))
                     ops.append(_PageOp(dl_ref, dwidth, "plain", (wh,),
                                        (), (), lanes, n, non_null))
-                elif dl_ref is not None:
-                    from .decode import page_plain_fixed_levels_tbl
-
-                    def op(s, p, _gw=plan_words, _d=dl_ref, _nn=non_null,
-                           _n=n, _lanes=lanes):
-                        vals, dl_dev = page_plain_fixed_levels_tbl(
-                            _gw(s), s[_d[0][0]], s[_d[0][1]], _nn, _lanes,
-                            _d[1], dwidth, _d[2], dsingle=_d[3],
-                        )
-                        p["def"].append((dl_dev, _n))
-                        p["val"].append((vals, _nn))
-
-                    ops.append(op)
                 else:
-                    ops.append(
-                        lambda s, p, _gw=plan_words, _nn=non_null,
-                        _lanes=lanes:
-                        p["val"].append(
-                            (plain_fixed_to_lanes(_gw(s), _nn, _lanes), _nn)
-                        )
-                    )
+                    kind, hs, statics, aux = fused_val or (
+                        "tokens", (), (), (0, 0))
+                    ops.append(_PageOp(dl_ref, dwidth, kind, hs, statics,
+                                       (), lanes, n, non_null, aux=aux,
+                                       words=plan_words))
             else:
                 _tr = "raw"
                 _def_standalone()
@@ -2121,7 +2129,7 @@ def plan_chunk_device(blob, cm: ColumnMetaData, node: SchemaNode,
             _tr = "delta-bp"
             _def_standalone()
             if ptype == Type.INT32:
-                build = _stage_delta_plan(
+                build, _ = _stage_delta_plan(
                     plan_delta_i32(values_seg), stager, need_hi=False)
                 ops.append(
                     lambda s, p, _b=build, _nn=non_null:
@@ -2130,7 +2138,7 @@ def plan_chunk_device(blob, cm: ColumnMetaData, node: SchemaNode,
                     )
                 )
             else:
-                build = _stage_delta_plan(
+                build, _ = _stage_delta_plan(
                     plan_delta_i64(values_seg), stager, need_hi=True)
                 ops.append(
                     lambda s, p, _b=build, _nn=non_null:
@@ -2196,17 +2204,16 @@ def plan_chunk_device(blob, cm: ColumnMetaData, node: SchemaNode,
         _pc.store(cache_key, _record, plan_cache_budget())
 
     type_length = node.element.type_length
+    chunk = _chunk_plan(ops, stager, max_def)
 
     def finish(staged) -> DeviceColumn:
         _cs = current_stats()
-        if len(ops) > 1 and all(type(op) is _PageOp for op in ops):
-            col = _chunk_column(ops, staged, ptype, type_length,
-                                max_def, total)
-            if col is not None:
-                if _cs is not None:
-                    _cs.chunks_fused += 1
-                    _cs.programs_dispatched += 1
-                return col
+        if chunk is not None:
+            if _cs is not None:
+                _cs.chunks_fused += 1
+                _cs.pages_fused += chunk.pages
+                _cs.programs_dispatched += 1
+            return _chunk_column(chunk, staged, ptype, type_length, total)
         parts = {"val": [], "bytes": [], "rep": [], "def": []}
         programs = 0
         for op in ops:
@@ -2255,6 +2262,8 @@ def plan_chunk_device(blob, cm: ColumnMetaData, node: SchemaNode,
                             positions, rep, dl, total,
                             n_packed=n_packed or 0)
 
+    # the value kinds of a fused chunk's groups, for the dispatch span
+    finish.kinds = chunk.kinds if chunk is not None else ""
     return finish
 
 
@@ -2295,22 +2304,39 @@ class _PageOp:
     kernel's bucketed statics and the page's exact counts.
 
     ``lev``: ``((bp, table) handles, cnt, nbp, single)`` of the def
-    levels (width ``dw``), or None; ``kind``: ``"dict"`` (fixed-width
-    dictionary gather), ``"plain"`` (staged PLAIN words) or
-    ``"dict_bytes"``; ``val``: the value stream's handles, ``(bp,
-    table)`` of the indices or ``(words,)``; ``statics``: ``(icnt, iw,
-    inbp, isingle)`` of the indices, plus the byte cap for
-    ``"dict_bytes"``; ``shared``: the dictionary's handles.
+    levels (width ``dw``), or None.  ``kind``, with its ``val`` handles
+    and ``statics``:
+
+    - ``"dict"``: a fixed-width dictionary gather; the indices' ``(bp,
+      table)`` and ``(icnt, iw, inbp, isingle)``;
+    - ``"dict_bytes"``: a BYTE_ARRAY dictionary gather; the same, plus
+      the byte cap;
+    - ``"plain"``: PLAIN fixed-width words staged raw, ``(words,)``;
+    - ``"plain_bytes"``: PLAIN BYTE_ARRAY bytes staged raw,
+      ``(bytes,)``;
+    - ``"planes"``: PLAIN fixed-width under the byte-plane transport;
+      its six staged arrays and ``(spec, stride)``
+      (``decode.planes_to_words``);
+    - ``"delta"``: PLAIN INT32/INT64 under the delta-lane transport;
+      its words and min_delta lanes, ``(n_vals, w, wide)``, and the
+      first value's (lo, hi) words in ``aux``;
+    - ``"tokens"``: PLAIN fixed-width under the snappy-token transport,
+      which only the per-page path decodes.
+
+    ``shared``: the dictionary's handles; ``offsets`` and ``nbytes``: a
+    byte-array page's host output offsets and byte count; ``words``:
+    the transport's own per-page expansion.
 
     Called as ``op(staged, parts)`` it enqueues the page's own kernels
     (``programs`` of them); ``finish()`` instead hands a chunk whose
-    pages are all such ops to one chunk program (``_chunk_column``)."""
+    pages are all such ops to one chunk program (``_chunk_plan``,
+    ``_chunk_column``)."""
 
     __slots__ = ("lev", "dw", "kind", "val", "statics", "shared",
-                 "lanes", "n", "nn", "offsets", "nbytes")
+                 "lanes", "n", "nn", "offsets", "nbytes", "aux", "words")
 
     def __init__(self, lev, dw, kind, val, statics, shared, lanes, n, nn,
-                 offsets=None, nbytes=0):
+                 offsets=None, nbytes=0, aux=(0, 0), words=None):
         self.lev = lev
         self.dw = dw
         self.kind = kind
@@ -2320,27 +2346,34 @@ class _PageOp:
         self.lanes = lanes
         self.n = n
         self.nn = nn
-        self.offsets = offsets  # "dict_bytes": host output offsets
+        self.offsets = offsets
         self.nbytes = nbytes
+        self.aux = aux
+        self.words = words
 
     @property
     def programs(self) -> int:
-        return 3 if self.kind == "dict_bytes" and self.lev else 1
+        if self.kind == "dict_bytes" and self.lev:
+            return 3
+        return 2 if self.kind == "plain_bytes" and self.lev else 1
 
     def __call__(self, s, p):
         from . import decode as dk
 
         lev, nn = self.lev, self.nn
         v = [s[h] for h in self.val]
-        if self.kind == "dict_bytes":
+        if self.offsets is not None:
             if lev is not None:
                 dl = dk.expand_tbl(s[lev[0][0]], s[lev[0][1]], lev[1],
                                    self.dw, lev[2], single=lev[3])
                 p["def"].append((dl.astype(jnp.int32), self.n))
-            icnt, iw, inbp, isingle, cap = self.statics
-            data = dk.page_dict_bytes_tbl(
-                s[self.shared[0]], s[self.shared[1]], v[0], v[1],
-                np.int32(nn), icnt, iw, inbp, cap, isingle=isingle)
+            if self.kind == "plain_bytes":
+                data = v[0]
+            else:
+                icnt, iw, inbp, isingle, cap = self.statics
+                data = dk.page_dict_bytes_tbl(
+                    s[self.shared[0]], s[self.shared[1]], v[0], v[1],
+                    np.int32(nn), icnt, iw, inbp, cap, isingle=isingle)
             p["bytes"].append((self.offsets, data, self.nbytes))
             return
         if self.kind == "dict":
@@ -2354,12 +2387,14 @@ class _PageOp:
                     s[self.shared[0]], s[lev[0][0]], s[lev[0][1]],
                     v[0], v[1], lev[1], self.dw, lev[2], icnt, iw, inbp,
                     lanes=self.lanes, dsingle=lev[3], isingle=isingle)
-        elif lev is None:
-            vals = plain_fixed_to_lanes(v[0], nn, self.lanes)
         else:
-            vals, dl = dk.page_plain_fixed_levels_tbl(
-                v[0], s[lev[0][0]], s[lev[0][1]], nn, self.lanes,
-                lev[1], self.dw, lev[2], dsingle=lev[3])
+            words = v[0] if self.kind == "plain" else self.words(s)
+            if lev is None:
+                vals = plain_fixed_to_lanes(words, nn, self.lanes)
+            else:
+                vals, dl = dk.page_plain_fixed_levels_tbl(
+                    words, s[lev[0][0]], s[lev[0][1]], nn, self.lanes,
+                    lev[1], self.dw, lev[2], dsingle=lev[3])
         if lev is not None:
             p["def"].append((dl, self.n))
         p["val"].append((vals, nn))
@@ -2377,19 +2412,41 @@ def _page_slots(n: int) -> int:
     return 1 << max(n - 1, 0).bit_length() if n <= 8 else -(-n // 8) * 8
 
 
-def _chunk_column(ops, staged, ptype, type_length, max_def, total):
-    """Decode a chunk whose pages are all :class:`_PageOp` in ONE
-    program (``decode.chunk_program``); None where its pages fall into
-    more than ``_MAX_CHUNK_GROUPS`` groups of equal statics and shapes.
+class _ChunkPlan(NamedTuple):
+    """A chunk's pages grouped for one ``decode.chunk_program``: each
+    group's pages' handles (padding slots repeat the first page), the
+    program's ``sig`` and ``meta`` rows, the value ``kinds`` of its
+    groups, and a byte-array chunk's host offsets and byte count."""
 
-    Pages group by kernel statics and staged shapes.  Each group's page
-    count pads to a bucket (``_page_slots``), and the running offsets
-    and counts go in as runtime data, so the program's key holds no
-    exact count.  Byte-array offsets are built on the host, as on the
-    per-page path."""
-    from .decode import bucket, chunk_program
+    shared: tuple
+    lev: tuple
+    val: tuple
+    sig: tuple
+    meta: np.ndarray
+    kinds: str
+    pages: int
+    n_packed: int
+    offsets: np.ndarray | None
+    n_bytes: int | None
 
-    if any((op.lev is not None) != bool(max_def) for op in ops):
+
+def _chunk_plan(ops, stager, max_def):
+    """Group a chunk whose pages are all :class:`_PageOp` for ONE
+    program, from the shapes the stager will give their arrays; None
+    for a single page, a snappy-token page, or where the pages fall
+    into more than ``_MAX_CHUNK_GROUPS`` groups of equal statics and
+    shapes.
+
+    Each group's page count pads to a bucket (``_page_slots``), and the
+    running offsets and counts go in as runtime data, so the program's
+    key holds no exact count.  Byte-array offsets are built here, on
+    the host."""
+    from .decode import bucket
+
+    if len(ops) < 2 or not all(type(op) is _PageOp for op in ops):
+        return None
+    if any((op.lev is not None) != bool(max_def) or op.kind == "tokens"
+           for op in ops):
         return None
     lanes = ops[0].lanes or 1
     levs, vals = {}, {}
@@ -2397,18 +2454,16 @@ def _chunk_column(ops, staged, ptype, type_length, max_def, total):
     for op in ops:
         if op.lev is not None:
             hs, cnt, nbp, single = op.lev
-            arrs = (staged[hs[0]], staged[hs[1]])
             statics = (cnt, op.dw, nbp, single)
-            levs.setdefault(statics + (arrs[0].shape, arrs[1].shape),
-                            (statics, []))[1].append(
-                (arrs, (lev_off, op.n, 0)))
+            shapes = tuple(_staged_shape(stager, h) for h in hs)
+            levs.setdefault(statics + shapes, (statics, []))[1].append(
+                (hs, (lev_off, op.n, 0, 0, 0)))
         lev_off += op.n
-        arrs = tuple(staged[h] for h in op.val)
         statics = (op.kind, *op.statics)
-        width = op.nbytes if op.kind == "dict_bytes" else op.nn * lanes
-        vals.setdefault(statics + tuple(a.shape for a in arrs),
-                        (statics, []))[1].append(
-            (arrs, (val_off, width, op.nn)))
+        shapes = tuple(_staged_shape(stager, h) for h in op.val)
+        width = op.nbytes if op.offsets is not None else op.nn * lanes
+        vals.setdefault(statics + shapes, (statics, []))[1].append(
+            (op.val, (val_off, width, op.nn, *op.aux)))
         val_off += width
     if len(levs) + len(vals) > _MAX_CHUNK_GROUPS:
         return None
@@ -2417,33 +2472,53 @@ def _chunk_column(ops, staged, ptype, type_length, max_def, total):
 
     def padded(groups):
         # sorted, so the key is the same whatever the pages' order
-        sig, inputs = [], []
+        sig, handles = [], []
         for key in sorted(groups):
             statics, pages = groups[key]
             pad = _page_slots(len(pages)) - len(pages)
-            inputs.append(tuple(a for a, _ in pages)
-                          + (pages[0][0],) * pad)
-            rows.extend([r for _, r in pages] + [(0, 0, 0)] * pad)
+            handles.append(tuple(h for h, _ in pages)
+                           + (pages[0][0],) * pad)
+            rows.extend([r for _, r in pages] + [(0,) * 5] * pad)
             sig.append(statics)
-        return tuple(sig), tuple(inputs)
+        return tuple(sig), tuple(handles)
 
-    lev_sig, lev_in = padded(levs)
-    val_sig, val_in = padded(vals)
-    shared = next((tuple(staged[h] for h in op.shared) for op in ops
-                   if op.shared), ())
-    sig = (lev_sig, val_sig, bucket(lev_off) if levs else 0,
-           bucket(val_off), lanes, max_def)
-    dl, data, mask, positions = chunk_program(
-        shared, lev_in, val_in, np.asarray(rows, dtype=np.int32), sig=sig)
-    n_packed = sum(op.nn for op in ops)
-    if ops[0].kind == "dict_bytes":
+    lev_sig, lev_handles = padded(levs)
+    val_sig, val_handles = padded(vals)
+    offsets = n_bytes = None
+    if ops[0].offsets is not None:
         offsets, n_bytes = _rebased_offsets(
             [(op.offsets, op.nbytes) for op in ops])
-        return DeviceColumn(ptype, type_length, data, jnp.asarray(offsets),
-                            mask, positions, None, dl, total,
-                            n_packed=n_packed, n_bytes=n_bytes)
+    return _ChunkPlan(
+        shared=next((op.shared for op in ops if op.shared), ()),
+        lev=lev_handles, val=val_handles,
+        sig=(lev_sig, val_sig, bucket(lev_off) if levs else 0,
+             bucket(val_off), lanes, max_def),
+        # aux words are unsigned: int32 by their bits
+        meta=np.asarray(rows, np.int64).astype(np.uint32).view(np.int32),
+        kinds=",".join(sorted({kind for kind, *_ in val_sig})),
+        pages=len(ops), n_packed=sum(op.nn for op in ops),
+        offsets=offsets, n_bytes=n_bytes)
+
+
+def _chunk_column(plan, staged, ptype, type_length, total):
+    """Decode a planned chunk (:func:`_chunk_plan`) in ONE program
+    (``decode.chunk_program``)."""
+    from .decode import chunk_program
+
+    def arrays(groups):
+        return tuple(tuple(tuple(staged[h] for h in hs) for hs in group)
+                     for group in groups)
+
+    dl, data, mask, positions = chunk_program(
+        tuple(staged[h] for h in plan.shared), arrays(plan.lev),
+        arrays(plan.val), plan.meta, sig=plan.sig)
+    if plan.offsets is not None:
+        return DeviceColumn(ptype, type_length, data,
+                            jnp.asarray(plan.offsets), mask, positions,
+                            None, dl, total, n_packed=plan.n_packed,
+                            n_bytes=plan.n_bytes)
     return DeviceColumn(ptype, type_length, data, None, mask, positions,
-                        None, dl, total, n_packed=n_packed)
+                        None, dl, total, n_packed=plan.n_packed)
 
 
 def _merge_parts(parts, lanes: int = 1):
@@ -2925,7 +3000,8 @@ def _finish_row_group(planned):
         staged_lists = _put_all([stager for _, _, stager in planned])
     out = {}
     for (path, finish, _), staged in zip(planned, staged_lists):
-        with _trace.stage("dispatch", "dispatch_s", column=path):
+        with _trace.stage("dispatch", "dispatch_s", column=path,
+                          kinds=getattr(finish, "kinds", "")):
             out[path] = finish(staged)
     # Drain the unit before returning: one batched block_until_ready
     # on its buffers, which also fences the finish()-time transfers

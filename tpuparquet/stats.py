@@ -92,10 +92,11 @@ class DecodeStats:
     # bytes or by the number of puts
     pieces_staged: int = 0
     # device programs each column's finish() enqueued: one per chunk
-    # decoded by a chunk program (chunks_fused counts those chunks);
-    # on the per-page path each page kernel, slice, concatenate and
-    # validity program
+    # decoded by a chunk program (chunks_fused counts those chunks,
+    # pages_fused their data pages); on the per-page path each page
+    # kernel, slice, concatenate and validity program
     chunks_fused: int = 0
+    pages_fused: int = 0
     programs_dispatched: int = 0
     # slow-path executions that a healthy build would run natively (e.g.
     # a stale .so forcing the numpy bp-stats fallback): nonzero means
@@ -285,7 +286,8 @@ class DecodeStats:
         "pages_device_planes", "pages_device_delta_lanes",
         "pages_device_encoded", "pages_host_values", "values",
         "bytes_compressed", "bytes_uncompressed", "bytes_staged",
-        "pieces_staged", "chunks_fused", "programs_dispatched",
+        "pieces_staged", "chunks_fused", "pages_fused",
+        "programs_dispatched",
         "bytes_read", "read_s",
         "native_fallbacks", "pages_crc_verified", "crc_mismatches",
         "faults_injected", "io_retries", "dispatch_retries",
@@ -358,6 +360,7 @@ class DecodeStats:
             "bytes_staged": self.bytes_staged,
             "pieces_staged": self.pieces_staged,
             "chunks_fused": self.chunks_fused,
+            "pages_fused": self.pages_fused,
             "programs_dispatched": self.programs_dispatched,
             "bytes_read": self.bytes_read,
             "read_s": round(self.read_s, 6),
