@@ -176,8 +176,8 @@ def test_chunk_program(one_chip, kind):
         lanes, shared = 1, (arr((32,), jnp.int32), arr((32,), jnp.uint8))
         val = (tuple(stream(32768, 1, 32) for _ in range(56)),
                (stream(16384, 1, 32),))
-        val_sig = (("dict_bytes", 32768, 1, 32768, True, 32768),
-                   ("dict_bytes", 16384, 1, 16384, True, 16384))
+        val_sig = (("dict_bytes", 32768, 1, 32768, True, 32768, 0),
+                   ("dict_bytes", 16384, 1, 16384, True, 16384, 0))
     else:
         val = (tuple((arr((40960,)),) for _ in range(56)),
                ((arr((32768,)),),))
@@ -206,7 +206,7 @@ def test_chunk_program_lineitem(one_chip, kind):
         shared = (arr((32768,), i32), arr((1 << 20,), u8))
         val = ((((arr((4096 * 17 // 32,)), arr((4, 32))),) * 2),
                ((arr((540672,), u8),),) * 56, ((arr((139264,), u8),),))
-        val_sig = (("dict_bytes", 4096, 17, 4096, True, 1 << 17),
+        val_sig = (("dict_bytes", 4096, 17, 4096, True, 1 << 17, 0),
                    ("plain_bytes",), ("plain_bytes",))
         total = 1 << 25
     elif kind == "planes":
@@ -229,6 +229,50 @@ def test_chunk_program_lineitem(one_chip, kind):
     meta = arr((sum(len(g) for g in val), 5), i32)
     sig = ((), val_sig, 0, total, lanes, 0)
     _compile(chunk_program, shared, (), val, meta, sig=sig)
+
+
+# lineitem string chunks of a 1,048,576-row group: (dictionary entries,
+# their bytes, index width, fixed entry length or 0, per group its page
+# slots, index count and byte cap)
+DICT_BYTES = {
+    "shipinstruct-256Ki": (4, 48, 2, 0, ((56, 32768, 1 << 18),
+                                         (1, 16384, 1 << 17))),
+    "comment-1Mi": (38_000, 1 << 20, 16, 0, ((1, 32768, 1 << 20),
+                                             (1, 32768, 1 << 19))),
+    "flag-fixed": (3, 3, 2, 1, ((56, 32768, 32768), (1, 16384, 16384))),
+}
+
+
+@pytest.mark.parametrize("shape", list(DICT_BYTES))
+def test_chunk_program_dict_bytes_lineitem(one_chip, shape):
+    """A lineitem string chunk's ``"dict_bytes"`` groups in one program:
+    ``l_shipinstruct`` (four entries of 4-17 bytes), ``l_comment``'s
+    two dictionary pages before its PLAIN fallback, and ``l_returnflag``
+    (one-byte entries, the fixed-length row gather).  Each must compile
+    in seconds: the byte lookup is a blocked running count, because one
+    ``cumsum`` over a million values, a ``reduce_window`` on the TPU,
+    took its compiler about 29 s (``decode._running_count``)."""
+    import time
+
+    from tpuparquet.kernels.decode import bucket, chunk_program
+
+    u32, u8, i32 = jnp.uint32, jnp.uint8, jnp.int32
+
+    def arr(shape, dtype=u32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    n_dict, n_bytes, w, fixed, groups = DICT_BYTES[shape]
+    shared = (arr((bucket(n_dict + 1),), i32), arr((bucket(n_bytes),), u8))
+    val = tuple(((arr((cnt // 32 * w,)), arr((4, 32))),) * slots
+                for slots, cnt, _ in groups)
+    val_sig = tuple(("dict_bytes", cnt, w, cnt, True, cap, fixed)
+                    for _, cnt, cap in groups)
+    total = bucket(sum(slots * cap for slots, _, cap in groups))
+    meta = arr((sum(slots for slots, _, _ in groups), 5), i32)
+    t0 = time.perf_counter()
+    _compile(chunk_program, shared, (), val, meta,
+             sig=((), val_sig, 0, total, 1, 0))
+    assert time.perf_counter() - t0 < 30
 
 
 def test_expand_tokens(one_chip):

@@ -358,36 +358,45 @@ def page_plain_fixed_levels_tbl(words, d_bp, d_tbl, count: int, lanes: int,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "icnt", "iw", "inbp", "total_bytes", "has_idx", "isingle"))
+    "icnt", "iw", "inbp", "total_bytes", "has_idx", "isingle", "width"))
 def page_dict_bytes_tbl(dict_offsets, dict_data, i_bp, i_tbl, non_null,
                         icnt: int, iw: int, inbp: int, total_bytes: int,
-                        has_idx: bool = True, isingle: bool = False):
+                        has_idx: bool = True, isingle: bool = False,
+                        width: int = 0):
     """Fused dict BYTE_ARRAY page decode: expand indices, derive the
     output offsets ON DEVICE (value lengths are just the dictionary
-    offset diffs; a masked cumsum rebuilds the padded offset table the
-    gather needs), then the byte-granular gather.  Shipping the offsets
-    cost 4 bytes per value — more wire than the dict indices themselves
-    for short-string columns; now only the run tables ship."""
+    offset diffs), then the byte-granular gather
+    (:func:`_dict_bytes_gather`).  Shipping the offsets cost 4 bytes
+    per value — more wire than the dict indices themselves for
+    short-string columns; now only the run tables ship."""
     if has_idx:
         idx = _expand_stream(i_bp, i_tbl, icnt, iw, inbp,
                              isingle).astype(jnp.int32)
     else:
         idx = jnp.zeros((icnt,), jnp.int32)
     return _dict_bytes_gather(dict_offsets, dict_data, idx, non_null,
-                              total_bytes)
+                              total_bytes, width)
 
 
 def _dict_bytes_gather(dict_offsets, dict_data, idx, non_null,
-                       total_bytes: int):
+                       total_bytes: int, width: int = 0):
+    """One page's bytes: the first ``non_null`` values of the
+    dictionary indices ``idx``, padded to ``total_bytes``.  ``width``
+    > 0: the planner found every dictionary entry ``width`` bytes long,
+    so the offsets step by ``width`` from 0 and value ``v`` is row
+    ``idx[v]`` of the dictionary viewed as ``(D, width)``: one row
+    gather, no offsets.  Otherwise the output offsets are a running
+    count of the valid values' lengths, for :func:`dict_gather_bytes`."""
+    if width:
+        out = _take_rows(dict_data, idx, width).reshape(-1)[:total_bytes]
+        return jnp.pad(out, (0, total_bytes - out.shape[0]))
     n_dict = dict_offsets.shape[0] - 1
     idx = jnp.clip(idx, 0, max(n_dict - 1, 0))
     lens = dict_offsets[1:] - dict_offsets[:-1]
     valid = jnp.arange(idx.shape[0], dtype=jnp.int32) < non_null
     contrib = jnp.where(valid, lens[idx], 0)
-    out_offsets = jnp.concatenate([
-        jnp.zeros((1,), dict_offsets.dtype),
-        jnp.cumsum(contrib).astype(dict_offsets.dtype),
-    ])
+    out_offsets = jnp.concatenate([jnp.zeros((1,), dict_offsets.dtype),
+                                   _running_count(contrib)])
     return dict_gather_bytes(dict_offsets, dict_data, idx, out_offsets,
                              total_bytes)
 
@@ -468,7 +477,7 @@ def _chunk_values(kind, statics, group, shared, rows, lanes):
         words = [dictionary[k::lanes][idx] for k in range(lanes)]
         return jnp.stack(words, axis=-1).reshape(idx.shape[0], -1)
     return jax.lax.map(lambda page: _dict_bytes_gather(
-        shared[0], shared[1], page[0], page[1], statics[4]),
+        shared[0], shared[1], page[0], page[1], *statics[4:]),
         (idx, rows[:, 2]))
 
 
@@ -509,7 +518,8 @@ def chunk_program(shared, lev_groups, val_groups, meta, sig):
     ``sig`` = ``(lev_sig, val_sig, lev_len, val_len, lanes, max_def)``:
     per level group its ``(cnt, w, nbp, single)``; per value group its
     kind and statics: ``"dict"`` and ``"dict_bytes"`` the indices'
-    ``(icnt, iw, inbp, isingle)``, plus the byte ``cap`` for
+    ``(icnt, iw, inbp, isingle)``, plus the byte ``cap`` and the
+    dictionary's one entry length (0 if they differ) for
     ``"dict_bytes"``; ``"plain"`` and ``"plain_bytes"`` none;
     ``"planes"`` the lane ``spec`` and slab ``stride``
     (:func:`planes_to_words`); ``"delta"`` ``(n_vals, w, wide)``
@@ -577,12 +587,19 @@ def plain_bytes_from_blob(blob: jax.Array, out_offsets: jax.Array, pos,
 def dict_gather_bytes(dict_offsets: jax.Array, dict_data: jax.Array,
                       indices: jax.Array, out_offsets: jax.Array,
                       total_bytes: int):
-    """Variable-length dictionary gather -> (out_offsets, out_data).
+    """Variable-length dictionary gather -> the values' bytes, padded
+    to ``total_bytes``: the device analogue of the reference's
+    per-value dict gather (``type_dict.go:39-59``), vectorized at byte
+    granularity.
 
-    For every output byte position, locate its value via searchsorted over
-    the output offsets, then its source byte in the dictionary blob —
-    the device analogue of the reference's per-value dict gather
-    (``type_dict.go:39-59``), vectorized at byte granularity.
+    Byte ``b`` of value ``v`` reads ``b + dict_offsets[indices[v]] -
+    out_offsets[v]`` of the blob.  That shift changes only where a
+    value starts, so each value's change of shift is added at its
+    output offset (one scatter of ``n`` values; empty values that share
+    an offset telescope to the last one's shift), and a blocked running
+    count (:func:`_running_count`) gives every byte its shift: one
+    linear pass over the bytes, no search.  Bytes past the last value
+    are padding, of no fixed content.
 
     A dictionary of all-empty strings has a zero-length blob (legal:
     ``type_bytearray.go:24-55`` decodes it with no special case); every
@@ -590,13 +607,13 @@ def dict_gather_bytes(dict_offsets: jax.Array, dict_data: jax.Array,
     over ``uint8[0]`` would be out of range, so short-circuit it."""
     if dict_data.shape[0] == 0:
         return jnp.zeros((total_bytes,), dtype=dict_data.dtype)
-    b = jnp.arange(total_bytes, dtype=jnp.int32)
-    val = jnp.searchsorted(out_offsets[1:], b, side="right").astype(jnp.int32)
-    val = jnp.minimum(val, indices.shape[0] - 1)
-    within = b - out_offsets[val]
-    src = dict_offsets[indices[val]] + within
-    src = jnp.clip(src, 0, dict_data.shape[0] - 1)
-    return dict_data[src]
+    starts = out_offsets[:-1]
+    shift = dict_offsets[indices] - starts
+    step = jnp.diff(shift, prepend=jnp.zeros((1,), shift.dtype))
+    marks = jnp.zeros((total_bytes,), shift.dtype).at[starts].add(
+        step, mode="drop")
+    src = jnp.arange(total_bytes, dtype=shift.dtype) + _running_count(marks)
+    return dict_data[jnp.clip(src, 0, dict_data.shape[0] - 1)]
 
 
 # ----------------------------------------------------------------------

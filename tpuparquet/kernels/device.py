@@ -1246,6 +1246,8 @@ def plan_chunk_device(blob, cm: ColumnMetaData, node: SchemaNode,
     dict_data_h = None
     dict_lens_np = None
     dict_len = 0
+    dict_fixed = 0         # every entry's length, if they share one
+    dict_pages = fixed_pages = 0  # byte-array dictionary data pages
     dict_host = None       # host copy, kept only for the degraded path
 
     # Deferred device work: each op is a closure (staged, parts) -> None
@@ -1328,6 +1330,8 @@ def plan_chunk_device(blob, cm: ColumnMetaData, node: SchemaNode,
                 dict_data_h = stager.add(dict_np.data)
                 dict_lens_np = dict_np.lengths()
                 dict_len = len(dict_lens_np)
+                if dict_len and (dict_lens_np == dict_lens_np[0]).all():
+                    dict_fixed = int(dict_lens_np[0])
             else:
                 arr = np.asarray(dict_np)
                 dict_len = arr.shape[0]
@@ -1807,13 +1811,15 @@ def plan_chunk_device(blob, cm: ColumnMetaData, node: SchemaNode,
                 # every dynamic input stays at its bucket size so the jit
                 # cache keys on buckets, not exact per-page counts
                 cap = bucket(max(total_b, 1))
+                dict_pages += 1
+                fixed_pages += dict_fixed > 0
                 if i_sc is not None:
                     i_args, i_cnt, i_nbp, i_single = plan_stream_args(
                         i_sc, non_null, width, expanded=idx_u)
                     ops.append(_PageOp(
                         dl_ref, dwidth, "dict_bytes",
                         stager.add_many(i_args, pad=False),
-                        (i_cnt, width, i_nbp, i_single, cap),
+                        (i_cnt, width, i_nbp, i_single, cap, dict_fixed),
                         (dict_offsets_h, dict_data_h), None, n,
                         non_null, offsets=out_offsets, nbytes=total_b))
                 else:
@@ -1822,14 +1828,14 @@ def plan_chunk_device(blob, cm: ColumnMetaData, node: SchemaNode,
                     def op(s, p, _icnt=bucket(max(non_null, 1)),
                            _cap=cap, _oo=out_offsets, _nn=non_null,
                            _tb=total_b, _doh=dict_offsets_h,
-                           _ddh=dict_data_h):
+                           _ddh=dict_data_h, _fx=dict_fixed):
                         from .decode import page_dict_bytes_tbl
 
                         dummy = jnp.zeros((1,), jnp.uint32)
                         data = page_dict_bytes_tbl(
                             s[_doh], s[_ddh], dummy, dummy,
                             np.int32(_nn), _icnt, 0, 0, _cap,
-                            has_idx=False,
+                            has_idx=False, width=_fx,
                         )
                         p["bytes"].append((_oo, data, _tb))
 
@@ -2208,6 +2214,9 @@ def plan_chunk_device(blob, cm: ColumnMetaData, node: SchemaNode,
 
     def finish(staged) -> DeviceColumn:
         _cs = current_stats()
+        if _cs is not None:
+            _cs.dict_bytes_pages += dict_pages
+            _cs.dict_bytes_fixed_pages += fixed_pages
         if chunk is not None:
             if _cs is not None:
                 _cs.chunks_fused += 1
@@ -2310,7 +2319,8 @@ class _PageOp:
     - ``"dict"``: a fixed-width dictionary gather; the indices' ``(bp,
       table)`` and ``(icnt, iw, inbp, isingle)``;
     - ``"dict_bytes"``: a BYTE_ARRAY dictionary gather; the same, plus
-      the byte cap;
+      the byte cap and the dictionary's one entry length (0 where the
+      lengths differ);
     - ``"plain"``: PLAIN fixed-width words staged raw, ``(words,)``;
     - ``"plain_bytes"``: PLAIN BYTE_ARRAY bytes staged raw,
       ``(bytes,)``;
@@ -2370,10 +2380,11 @@ class _PageOp:
             if self.kind == "plain_bytes":
                 data = v[0]
             else:
-                icnt, iw, inbp, isingle, cap = self.statics
+                icnt, iw, inbp, isingle, cap, fixed = self.statics
                 data = dk.page_dict_bytes_tbl(
                     s[self.shared[0]], s[self.shared[1]], v[0], v[1],
-                    np.int32(nn), icnt, iw, inbp, cap, isingle=isingle)
+                    np.int32(nn), icnt, iw, inbp, cap, isingle=isingle,
+                    width=fixed)
             p["bytes"].append((self.offsets, data, self.nbytes))
             return
         if self.kind == "dict":

@@ -98,6 +98,11 @@ class DecodeStats:
     chunks_fused: int = 0
     pages_fused: int = 0
     programs_dispatched: int = 0
+    # dictionary BYTE_ARRAY data pages decoded on the device, fused or
+    # per page, and those whose dictionary entries all have one length
+    # (gathered as rows of a (D, length) view, with no offsets)
+    dict_bytes_pages: int = 0
+    dict_bytes_fixed_pages: int = 0
     # slow-path executions that a healthy build would run natively (e.g.
     # a stale .so forcing the numpy bp-stats fallback): nonzero means
     # perf has quietly regressed with no functional symptom
@@ -287,7 +292,7 @@ class DecodeStats:
         "pages_device_encoded", "pages_host_values", "values",
         "bytes_compressed", "bytes_uncompressed", "bytes_staged",
         "pieces_staged", "chunks_fused", "pages_fused",
-        "programs_dispatched",
+        "programs_dispatched", "dict_bytes_pages", "dict_bytes_fixed_pages",
         "bytes_read", "read_s",
         "native_fallbacks", "pages_crc_verified", "crc_mismatches",
         "faults_injected", "io_retries", "dispatch_retries",
@@ -362,6 +367,8 @@ class DecodeStats:
             "chunks_fused": self.chunks_fused,
             "pages_fused": self.pages_fused,
             "programs_dispatched": self.programs_dispatched,
+            "dict_bytes_pages": self.dict_bytes_pages,
+            "dict_bytes_fixed_pages": self.dict_bytes_fixed_pages,
             "bytes_read": self.bytes_read,
             "read_s": round(self.read_s, 6),
             "native_fallbacks": self.native_fallbacks,
@@ -441,7 +448,9 @@ class DecodeStats:
                f" / plan wait {d['plan_wait_s']:.3f}s / transfer "
                f"{d['transfer_s']:.3f}s / dispatch {d['dispatch_s']:.3f}s"
                f" ({d['programs_dispatched']:,} programs, "
-               f"{d['chunks_fused']}/{d['chunks']} chunks fused)"
+               f"{d['chunks_fused']}/{d['chunks']} chunks fused, "
+               f"{d['dict_bytes_fixed_pages']}/{d['dict_bytes_pages']} "
+               f"byte-array dictionary pages fixed-length)"
                f" / drain {d['drain_s']:.3f}s"
                if d["transfer_s"] else "")
             + (f"; {d['native_fallbacks']} native fallbacks (stale .so?)"
