@@ -317,19 +317,48 @@ class TestSamplingMechanics:
 # ----------------------------------------------------------------------
 
 class TestTraceCorrelation:
-    def test_samples_name_real_spans_of_the_scan(self, tmp_path):
+    def test_samples_name_real_spans_of_the_scan(self, tmp_path,
+                                                 monkeypatch):
+        from tpuparquet.kernels import device as device_mod
+
         paths = [write_file(tmp_path / f"c{i}.parquet", rows=3000,
                             seed=i * 100) for i in range(2)]
         trace.set_tracing(True)
         p = profiler_mod.set_profiling(True, hz=100, start=False)
         scan = ShardedScan(paths)
-        for _k, cols in scan.run_iter():
-            # drive the sampler from the consumer while the worker
-            # pool decodes the next units concurrently
-            p.sample_once()
-            p.sample_once()
-            for c in cols.values():
-                c.block_until_ready()
+        # hold the last unit's first plan task until the consumer has
+        # sampled at the unit before it: a worker is then inside that
+        # unit's span whatever the pool's speed (the window submits
+        # unit k+1 before it yields unit k).  Late, because the
+        # sampler keeps only its newest 512 samples in ``recent``.
+        fi_last, rg_last = scan.units[-1]
+        reader_last = scan.readers[fi_last]
+        real_plan = device_mod._plan_one_column
+        entered, release = threading.Event(), threading.Event()
+
+        def held_plan(reader, rg_index, *args, **kwargs):
+            if reader is reader_last and rg_index == rg_last \
+                    and not entered.is_set():
+                entered.set()
+                release.wait(10)
+            return real_plan(reader, rg_index, *args, **kwargs)
+
+        monkeypatch.setattr(device_mod, "_plan_one_column", held_plan)
+        try:
+            for k, cols in scan.run_iter():
+                held = k == len(scan.units) - 2
+                if held:
+                    assert entered.wait(10), "the last unit never planned"
+                # drive the sampler from the consumer while the worker
+                # pool decodes the next units concurrently
+                p.sample_once()
+                p.sample_once()
+                if held:
+                    release.set()
+                for c in cols.values():
+                    c.block_until_ready()
+        finally:
+            release.set()
         profiler_mod.set_profiling(False)
         spans = trace.snapshot_spans()
         span_ids = {(s["trace"], s["span"]) for s in spans}

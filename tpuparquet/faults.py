@@ -37,7 +37,7 @@ site                                  instrumented where / supported kinds
                                       — ``corrupt``, ``truncate``
 ``kernels.device.page_dispatch``      device plan, per data page
                                       — ``dispatch``
-``kernels.device.unit_dispatch``      ``_finish_row_group`` (per unit)
+``kernels.device.unit_dispatch``      ``_finish_unit`` (per unit)
                                       — ``dispatch``
 ``format.footer.tail``                8-byte length+magic tail read
                                       (``format/footer.py``)
@@ -52,7 +52,7 @@ site                                  instrumented where / supported kinds
                                       ``file`` so a rule can hang ONE
                                       replica) — ``hang``
 ``kernels.device.hang``               device dispatch
-                                      (``_finish_row_group``) — ``hang``
+                                      (``_finish_unit``) — ``hang``
 ``format.pageindex``                  page-index / bloom-filter blob
                                       reads (``io/reader.py``) —
                                       ``oserror``, ``transient``,

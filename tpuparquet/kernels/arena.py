@@ -144,7 +144,7 @@ class ArenaPool:
     planners never share a slab (the old per-unit arena would be
     written by several column planners at once).  Leases are returned
     only after the unit's transfers have drained
-    (``_finish_row_group``'s batched ``block_until_ready``), which is
+    (``_finish_unit``'s batched ``block_until_ready``), which is
     the same lifetime contract ``HostArena.release_all`` documents —
     the pool just moves the recycling boundary from thread-local to
     task-scoped.  Error paths simply DROP their lease references

@@ -799,7 +799,7 @@ class DeviceColumn:
 
     def _buffers(self):
         """Every live device buffer (the single source of truth for
-        batched syncs — block_until_ready AND _finish_row_group fence
+        batched syncs — block_until_ready AND _finish_unit fence
         through this, so a new slot added here is fenced everywhere)."""
         return [
             x for x in (self._data_p, self.offsets, self._mask_p,
@@ -2617,105 +2617,51 @@ def stage_chunkdata(cd, node) -> DeviceColumn:
         num, n_packed=n_packed, n_bytes=n_bytes)
 
 
-def _read_row_group_device_filtered(reader, rg_index: int, filt,
-                                    verdict) -> dict[str, DeviceColumn]:
-    """Late-materialized device read: filter columns decode on host,
-    the predicate evaluates exactly, and only surviving rows stage to
-    the device (``stage_chunkdata``).  Pruned pages are never
-    decompressed; pruned row groups return schema-shaped empty
-    columns.  Bit-exact vs decoding everything and post-filtering on
-    device."""
-    from ..filter import read_row_group_filtered
-
-    chunks, _rows = read_row_group_filtered(reader, rg_index, filt,
-                                            verdict)
-    with _trace.stage("transfer", "transfer_s", columns=len(chunks)):
-        out = {path: stage_chunkdata(cd, reader.schema.leaf(path))
-               for path, cd in chunks.items()}
-        jax.block_until_ready(
-            [x for c in out.values() for x in c._buffers()])
-    return out
-
-
 def read_row_group_device(reader, rg_index: int, filter=None,
                           verdict=None) -> dict[str, DeviceColumn]:
     """Decode the selected columns of one row group onto the device.
 
     The device-path sibling of ``FileReader.read_row_group_arrays``: same
-    selection semantics, device-resident results.  Each column chunk
-    plans as an independent task — on multi-core hosts a SINGLE large
-    row group (the common TPU-input shape) fans its columns across the
-    plan pool — then all columns' plan tables and page words ship in one
-    batched wave transfer (``_put_all``) and each column's chunk
-    program (or page kernels) dispatch and are drained before
-    returning (see the comment in ``_finish_row_group``).  For
-    multi-row-group reads prefer :func:`read_row_groups_device`, which
-    additionally overlaps row group N+1's host planning with N's
-    transfer.
+    selection semantics, device-resident results.  The one-unit driver
+    of the unit pipeline: :func:`_submit_unit` plans each column chunk
+    as a task on a pool of ``_plan_threads()`` workers — so a SINGLE
+    large row group (the common TPU-input shape) fans its columns across
+    the pool — and :func:`_finish_unit` ships all columns' plan tables
+    and page words in one batched wave transfer (``_put_all``) and
+    dispatches and drains each column's chunk program (or page kernels)
+    before returning.  It opens no ``unit`` span: its callers own the
+    unit (``resilient_unit_scan`` opens one around it).  For
+    multi-row-group reads prefer :func:`read_row_groups_device`, whose
+    window (:func:`pipelined_reads`) additionally overlaps row group
+    N+1's host planning with N's transfer.
 
     ``filter`` (a :mod:`tpuparquet.filter` expression, optionally with
     a precomputed ``verdict``) switches to the late-materialized
     pushdown path: filter columns decode on host first, pruned pages
     are never decompressed, and only surviving rows transfer —
     bit-exact vs decode-everything-then-post-filter."""
+    from concurrent.futures import ThreadPoolExecutor
+
     from ..stats import current_stats
 
     _cs = current_stats()
     if _cs is not None:
         _cs.row_groups += 1
-    if filter is not None:
-        try:
-            return _read_row_group_device_filtered(
-                reader, rg_index, filter, verdict)
-        except ScanError as e:
-            raise e.annotate(row_group=rg_index)
-    rg = reader.meta.row_groups[rg_index]
-    arenas = []
-    try:
-        cols = reader.selected_chunks(rg)
+    if filter is None:
         # remote sources: batch-prefetch the row group's chunk ranges
-        # (coalesced, parallel) so the column planners below hit the
-        # disk tier instead of issuing one round trip each.  No-op for
+        # (coalesced, parallel) so the column planners hit the disk
+        # tier instead of issuing one round trip each.  No-op for
         # local/in-memory sources.
         pf = getattr(reader, "prefetch_chunks", None)
         if pf is not None:
-            pf(rg)
-        n_workers = min(_plan_threads(), max(len(cols), 1))
-        if n_workers <= 1:
-            # serial path: plan on the calling thread under the caller's
-            # collector — byte-identical plans, no pool overhead.  One
-            # arena serves every column (no racing planners here), so
-            # decompression slabs recycle across columns like the
-            # pre-column-parallel planner's did.
-            a = lease_arena()
-            arenas.append(a)
-            planned = []
-            for path, node, cm in cols:
-                planned.append(
-                    _plan_one_column(reader, rg_index, path, node, cm, a))
-        else:
-            from concurrent.futures import ThreadPoolExecutor
-
-            degraded = _host_values_only()
-            with ThreadPoolExecutor(max_workers=n_workers) as ex:
-                futs = []
-                for path, node, cm in cols:
-                    a = lease_arena()
-                    arenas.append(a)
-                    futs.append(ex.submit(
-                        _plan_column_task, reader, rg_index, path, node,
-                        cm, a, _cs, degraded))
-                planned, err = _join_plans(futs, _cs)
-                if err is not None:
-                    raise err
-        out = _finish_row_group(planned)
-    except ScanError as e:
-        # arenas are dropped, not recycled: in-flight transfers (or
-        # abandoned plan tasks) may still read their slabs
-        raise e.annotate(row_group=rg_index)
-    for a in arenas:
-        return_arena(a)
-    return out
+            pf(reader.meta.row_groups[rg_index])
+    n_workers = _plan_threads()
+    with ThreadPoolExecutor(max_workers=n_workers) as ex:
+        futs, arenas = _submit_unit(ex, n_workers, reader, rg_index, _cs,
+                                    filter=filter, verdict=verdict,
+                                    tctx=_trace.current_ctx())
+        return _finish_unit(reader, rg_index, futs, arenas, _cs,
+                            filtered=filter is not None)
 
 
 def read_row_group_device_resilient(reader, rg_index: int,
@@ -2939,6 +2885,61 @@ def _plan_column_task(reader, rg_index: int, path, node, cm,
     return entry, ws
 
 
+def _plan_filtered_task(reader, rg_index: int, filt, verdict, like,
+                        degraded: bool, tctx=None, usp=None):
+    """The filtered unit's one plan task: the late-materialized host
+    decode (:func:`~tpuparquet.filter.read_row_group_filtered` — filter
+    columns first, pruned pages skipped, survivors gathered) under the
+    same per-thread collector, degradation state and trace context
+    discipline as :func:`_plan_column_task`.  Returns ``(chunks, ws)``:
+    ``chunks`` maps each selected column to its surviving rows'
+    :class:`~tpuparquet.io.chunk.ChunkData`."""
+    from ..filter import read_row_group_filtered
+    from ..stats import worker_stats
+
+    deg_ctx = (cpu_fallback_values() if degraded
+               else contextlib.nullcontext())
+    if usp is not None:
+        usp.setdefault("t0_exec", time.perf_counter())
+    with _trace.adopt(tctx), worker_stats(like=like) as ws, deg_ctx, \
+            _trace.stage("plan", "plan_s", cpu="plan_cpu_s",
+                         filtered=True):
+        chunks, _rows = read_row_group_filtered(reader, rg_index, filt,
+                                                verdict)
+    return chunks, ws
+
+
+def _submit_unit(ex, n_workers: int, reader, rg_index: int, like, *,
+                 filter=None, verdict=None, tctx=None, usp=None):
+    """Submit one unit's plan work to the pool ``ex`` (of ``n_workers``
+    workers); returns ``(futures, leased arenas)`` for
+    :func:`_finish_unit`.  Unfiltered, one :func:`_plan_column_task`
+    per selected column; with ``filter``, one
+    :func:`_plan_filtered_task` for the whole unit.  ``like`` is the
+    collector the tasks' counts merge into, ``tctx`` the trace context
+    they re-enter and ``usp`` the unit's open span, if any."""
+    degraded = _host_values_only()  # thread-local: workers re-enter it
+    if filter is not None:
+        return [ex.submit(_plan_filtered_task, reader, rg_index, filter,
+                          verdict, like, degraded, tctx, usp)], []
+    cols = reader.selected_chunks(reader.meta.row_groups[rg_index])
+    futs, ars = [], []
+    # single-worker pools run a unit's column tasks sequentially,
+    # so one shared arena per unit keeps the cross-column slab
+    # reuse; real parallelism needs a lease per racing task
+    shared = lease_arena() if n_workers == 1 and cols else None
+    if shared is not None:
+        ars.append(shared)
+    for path, node, cm in cols:
+        a = shared
+        if a is None:
+            a = lease_arena()
+            ars.append(a)
+        futs.append(ex.submit(_plan_column_task, reader, rg_index, path,
+                              node, cm, a, like, degraded, tctx, usp))
+    return futs, ars
+
+
 def _join_plans(futs, st, parent=None):
     """The consumer's wait on one unit's plan tasks, in column order
     (the ``plan_wait`` stage; ``parent`` is the unit's span ctx): each
@@ -2958,71 +2959,86 @@ def _join_plans(futs, st, parent=None):
     return planned, err
 
 
-def _plan_row_group(reader, rg, stager: _Stager, arena: HostArena):
-    """Serial compat path (tools/exp_gap.py and friends): plan every
-    selected column of one row group into ONE shared stager on the
-    calling thread.  The production readers plan per-column stagers via
-    :func:`_plan_one_column` instead."""
-    planned = []
-    verify_crc = getattr(reader, "_verify_crc", None)
-    with _trace.stage("plan", "plan_s", cpu="plan_cpu_s") as sp:
-        for path, node, cm, blob, start in \
-                reader.iter_selected_chunks(rg):
-            try:
-                planned.append(
-                    (path,
-                     plan_chunk_device(memoryview(blob), cm, node, start,
-                                       stager, arena,
-                                       verify_crc=verify_crc))
-                )
-            except ScanError as e:
-                raise e.annotate(column=path,
-                                 file=getattr(reader, "name", None))
-            except ValueError as e:
-                # codec-layer domain errors become taxonomy errors with
-                # coordinates; raw crash types propagate as the bugs
-                # they are (the crash-corpus clean-failure contract)
-                raise CorruptChunkError(
-                    str(e), column=path,
-                    file=getattr(reader, "name", None)) from e
-        sp.note(columns=len(planned))
-    return planned
+def _finish_unit(reader, rg_index: int, futs, arenas, like, *,
+                 filtered: bool = False, usp=None):
+    """Join one unit's plan tasks (from :func:`_submit_unit`, in column
+    order) and put the unit on the device.
 
+    Unfiltered, ``planned`` is ``[(path, finish, stager)]`` from
+    :func:`_plan_one_column`: all columns' arrays ship in ONE shared
+    wave sequence (``_put_all``, in column order — wave composition is
+    independent of plan-thread count), then each column's device work
+    is enqueued (one ``dispatch`` stage per column: one chunk program,
+    or the per-page programs of a chunk that takes the per-page path)
+    and the unit's buffers drained (``drain``).  Filtered, the surviving
+    rows are staged by :func:`stage_chunkdata` and drained inside the
+    ``transfer`` stage.  The unit's arenas recycle once its buffers have
+    drained.  Any ``ScanError`` leaves annotated with ``row_group=``.
+    ``usp`` is the unit's open span: its children parent under it, and
+    it starts when its first plan task ran.
 
-def _finish_row_group(planned):
-    """Stage + dispatch one unit's column plans: ``planned`` is
-    ``[(path, finish, stager)]`` from :func:`_plan_one_column`.  All
-    columns' arrays ship in ONE shared wave sequence (``_put_all``, in
-    column order — wave composition is identical to the old single-
-    stager path and independent of plan-thread count).  Then each
-    column's device work is enqueued (one ``dispatch`` stage per
-    column: one chunk program, or the per-page programs of a chunk that
-    takes the per-page path) and the unit's buffers drained
-    (``drain``)."""
-    if not _host_values_only():
-        # unit-level simulated device failures (harness sites); skipped
-        # on the degraded re-plan, whose remaining device work is bare
-        # buffer staging.  The hang site simulates a wedged
-        # accelerator: under a dispatch deadline it becomes a
-        # DispatchDeadlineError instead of a stalled scan.
-        fault_point("kernels.device.unit_dispatch")
-        fault_point("kernels.device.hang")
-    with _trace.stage("transfer", "transfer_s", columns=len(planned)):
-        staged_lists = _put_all([stager for _, _, stager in planned])
-    out = {}
-    for (path, finish, _), staged in zip(planned, staged_lists):
-        with _trace.stage("dispatch", "dispatch_s", column=path,
-                          kinds=getattr(finish, "kinds", "")):
-            out[path] = finish(staged)
-    # Drain the unit before returning: one batched block_until_ready
-    # on its buffers, which also fences the finish()-time transfers
-    # sourced from arena slabs before the slabs recycle.  With one
-    # program per chunk the enqueue is short, so the drain carries the
-    # device time of the unit's chunk programs, which start only once
-    # the unit's transfer is done.
-    with _trace.stage("drain", "drain_s", columns=len(out)):
-        jax.block_until_ready(
-            [x for c in out.values() for x in c._buffers()])
+    The chunk programs trace under this frame on a unit's first decode.
+    It calls ``finish()`` itself rather than through a helper: a helper
+    frame between the window and the trace made set-up 8-9 s longer in
+    both benchmark cells on a TPU v5e."""
+    parent = _trace.ctx_of(usp)
+    try:
+        planned, err = _join_plans(futs, like, parent=parent)
+        if usp is not None and "t0_exec" in usp:
+            # the unit span starts when its first plan task RAN
+            # (stamped by the worker; all futures joined above), not
+            # when the window submitted it — queue wait belongs to the
+            # scan's driver time, not the unit
+            usp["t0"] = usp["t0_exec"]
+        if err is not None:
+            raise err
+        with _trace.adopt(parent):
+            if filtered:
+                chunks = planned[0]
+                with _trace.stage("transfer", "transfer_s",
+                                  columns=len(chunks)):
+                    out = {path: stage_chunkdata(
+                               cd, reader.schema.leaf(path))
+                           for path, cd in chunks.items()}
+                    jax.block_until_ready(
+                        [x for c in out.values() for x in c._buffers()])
+            else:
+                if not _host_values_only():
+                    # unit-level simulated device failures (harness
+                    # sites); skipped on the degraded re-plan, whose
+                    # remaining device work is bare buffer staging.
+                    # The hang site simulates a wedged accelerator:
+                    # under a dispatch deadline it becomes a
+                    # DispatchDeadlineError instead of a stalled scan.
+                    fault_point("kernels.device.unit_dispatch")
+                    fault_point("kernels.device.hang")
+                with _trace.stage("transfer", "transfer_s",
+                                  columns=len(planned)):
+                    staged_lists = _put_all(
+                        [stager for _, _, stager in planned])
+                out = {}
+                for (path, finish, _), staged in zip(planned,
+                                                     staged_lists):
+                    with _trace.stage("dispatch", "dispatch_s",
+                                      column=path,
+                                      kinds=getattr(finish, "kinds", "")):
+                        out[path] = finish(staged)
+                # Drain the unit before returning: one batched
+                # block_until_ready on its buffers, which also fences
+                # the finish()-time transfers sourced from arena slabs
+                # before the slabs recycle.  With one program per chunk
+                # the enqueue is short, so the drain carries the device
+                # time of the unit's chunk programs, which start only
+                # once the unit's transfer is done.
+                with _trace.stage("drain", "drain_s", columns=len(out)):
+                    jax.block_until_ready(
+                        [x for c in out.values() for x in c._buffers()])
+    except ScanError as e:
+        # arenas are dropped, not recycled: in-flight transfers (or
+        # abandoned plan tasks) may still read their slabs
+        raise e.annotate(row_group=rg_index)
+    for a in arenas:
+        return_arena(a)
     return out
 
 
@@ -3077,119 +3093,36 @@ def _usable_cpus() -> int:
         return os.cpu_count() or 1
 
 
-def filtered_pipelined_reads(readers, units, device_for=None,
-                             start: int = 0, *, filter=None,
-                             verdicts=None):
-    """The late-materialization sibling of :func:`pipelined_reads`:
-    each unit's filtered host decode (filter columns first, pruned
-    pages skipped, survivors gathered) runs as one pool task while the
-    main thread stages the previous unit's survivors on its device —
-    plan/transfer overlap is preserved, just at unit granularity
-    (filtered decode is one fused host pass, not per-column plan
-    tasks).  ``verdicts`` optionally maps ``(file, rg)`` to a
-    precomputed :class:`~tpuparquet.filter.PruneVerdict` so the scan's
-    unit-forming pass is not re-run per unit."""
-    from concurrent.futures import ThreadPoolExecutor
-
-    from ..filter import read_row_group_filtered
-    from ..stats import current_stats, worker_stats
-
-    order = list(range(start, len(units)))
-    if not order:
-        return
-    _cs = current_stats()
-    n_workers = _plan_threads()
-    degraded = _host_values_only()
-
-    def task(ri, rgi, tctx=None, usp=None):
-        deg_ctx = (cpu_fallback_values() if degraded
-                   else contextlib.nullcontext())
-        if usp is not None:
-            usp.setdefault("t0_exec", time.perf_counter())
-        v = None if verdicts is None else verdicts.get((ri, rgi))
-        with _trace.adopt(tctx), worker_stats(like=_cs) as ws, deg_ctx, \
-                _trace.stage("plan", "plan_s", cpu="plan_cpu_s",
-                             filtered=True):
-            chunks, _rows = read_row_group_filtered(
-                readers[ri], rgi, filter, v)
-        return chunks, ws
-
-    ex = ThreadPoolExecutor(max_workers=n_workers)
-    inflight = {}
-    unit_spans = {}
-    state = {"next_j": 0}
-
-    def fill(window: int):
-        while state["next_j"] < len(order) and len(inflight) < window:
-            k = order[state["next_j"]]
-            state["next_j"] += 1
-            ri, rgi = units[k]
-            usp = None
-            if _trace._active is not None:
-                usp = _trace.open_span("unit", push=False, unit=k,
-                                       file=ri, row_group=rgi)
-            unit_spans[k] = usp
-            inflight[k] = ex.submit(task, ri, rgi, _trace.ctx_of(usp),
-                                    usp)
-
-    try:
-        fill(n_workers + 1)
-        for k in order:
-            usp = unit_spans.pop(k, None)
-            planned, err = _join_plans([inflight.pop(k)], _cs,
-                                       parent=_trace.ctx_of(usp))
-            if err is not None:
-                _trace.close_span(usp, status="error",
-                                  error=type(err).__name__)
-                raise err
-            chunks = planned[0]
-            if usp is not None and "t0_exec" in usp:
-                usp["t0"] = usp["t0_exec"]
-            if _cs is not None:
-                _cs.row_groups += 1
-            reader = readers[units[k][0]]
-            dev_ctx = (jax.default_device(device_for(k))
-                       if device_for is not None
-                       else contextlib.nullcontext())
-            with dev_ctx, _trace.stage("transfer", "transfer_s",
-                                       parent=_trace.ctx_of(usp),
-                                       columns=len(chunks)):
-                out = {path: stage_chunkdata(
-                           cd, reader.schema.leaf(path))
-                       for path, cd in chunks.items()}
-                jax.block_until_ready(
-                    [x for c in out.values() for x in c._buffers()])
-            _trace.close_span(usp)
-            fill(n_workers + 1)
-            yield k, out
-    finally:
-        ex.shutdown(wait=True)
-        for usp in unit_spans.values():
-            _trace.close_span(usp, status="cancelled")
-        unit_spans.clear()
-
-
-def pipelined_reads(readers, units, device_for=None, start: int = 0):
+def pipelined_reads(readers, units, device_for=None, start: int = 0, *,
+                    filter=None, verdicts=None):
     """Yield ``(unit_index, {path: DeviceColumn})`` for
     ``units[start:]`` (each a ``(reader_index, rg_index)`` pair),
-    overlapping host planning with device transfer.
+    overlapping host planning with device transfer.  The one unit
+    window: under ``read_row_groups_device`` and the fail-fast scan
+    drivers in ``shard/``, filtered or not.
 
-    One shared pool of ``_plan_threads()`` workers runs PER-COLUMN plan
-    tasks (file reads, block decompression, run-table scans — all
-    GIL-releasing C/numpy work) while the main thread transfers and
-    dispatches unit N on its assigned device (``device_for(unit_index)``,
-    default device when None; plans are device-independent, so the
-    target only matters at transfer time).  Column granularity means
+    One shared pool of ``_plan_threads()`` workers runs each unit's plan
+    tasks (:func:`_submit_unit`: file reads, block decompression,
+    run-table scans — all GIL-releasing C/numpy work) while the main
+    thread transfers and dispatches unit N on its assigned device
+    (:func:`_finish_unit`; ``device_for(unit_index)``, default device
+    when None; plans are device-independent, so the target only matters
+    at transfer time).  Unfiltered units plan one task per column, so
     workers steal across units: a single wide row group fans out, and a
-    fast unit's idle workers pull the next unit's columns — not one
-    future per row group.  The submission window is derived from
-    in-flight TASKS (at least ``n_workers + 1`` column tasks and one
-    whole unit ahead), and every task leases its own arena from the
-    shared pool (``kernels/arena.py``) so racing planners never share a
-    slab; leases recycle only after the unit's transfers drain.
-    Results are identical to a serial :func:`read_row_group_device`
-    loop at any thread count.  The single shared pipeline under
-    ``read_row_groups_device`` and the scan drivers in ``shard/``."""
+    fast unit's idle workers pull the next unit's columns.  The
+    submission window is derived from in-flight TASKS (at least
+    ``n_workers + 1`` tasks and one whole unit ahead), and every column
+    task leases its own arena from the shared pool
+    (``kernels/arena.py``) so racing planners never share a slab; leases
+    recycle only after the unit's transfers drain.
+
+    ``filter`` (with ``verdicts`` optionally mapping ``(reader_index,
+    rg_index)`` to a precomputed
+    :class:`~tpuparquet.filter.PruneVerdict`) decodes each unit
+    late-materialized as ONE task — filter columns first, pruned pages
+    skipped — and stages only the surviving rows; one task per unit
+    keeps ``n_workers + 1`` units in flight.  Results are identical to
+    a serial :func:`read_row_group_device` loop at any thread count."""
     from concurrent.futures import ThreadPoolExecutor
 
     from ..stats import current_stats
@@ -3199,11 +3132,9 @@ def pipelined_reads(readers, units, device_for=None, start: int = 0):
         return
     _cs = current_stats()
     n_workers = _plan_threads()
-    degraded = _host_values_only()  # thread-local: workers re-enter it
 
     ex = ThreadPoolExecutor(max_workers=n_workers)
-    inflight = {}    # unit k -> [future per column, in column order]
-    arenas_of = {}   # unit k -> [leased arenas]
+    inflight = {}    # unit k -> (plan futures, leased arenas)
     unit_spans = {}  # unit k -> open trace span handle (or None)
     state = {"next_j": 0, "tasks": 0}
 
@@ -3211,8 +3142,6 @@ def pipelined_reads(readers, units, device_for=None, start: int = 0):
         k = order[state["next_j"]]
         state["next_j"] += 1
         ri, rgi = units[k]
-        reader = readers[ri]
-        cols = reader.selected_chunks(reader.meta.row_groups[rgi])
         # unit span: opened WITHOUT pushing the ambient context (its
         # open/close straddles generator yields) — the plan tasks and
         # the finish step re-enter it explicitly, so a unit's spans
@@ -3222,25 +3151,11 @@ def pipelined_reads(readers, units, device_for=None, start: int = 0):
             usp = _trace.open_span("unit", push=False, unit=k,
                                    file=ri, row_group=rgi)
         unit_spans[k] = usp
-        tctx = _trace.ctx_of(usp)
-        futs, ars = [], []
-        # single-worker pools run a unit's column tasks sequentially,
-        # so one shared arena per unit keeps the old cross-column slab
-        # reuse; real parallelism needs a lease per racing task
-        shared = lease_arena() if n_workers == 1 and cols else None
-        if shared is not None:
-            ars.append(shared)
-        for path, node, cm in cols:
-            a = shared
-            if a is None:
-                a = lease_arena()
-                ars.append(a)
-            futs.append(ex.submit(_plan_column_task, reader, rgi, path,
-                                  node, cm, a, _cs, degraded, tctx,
-                                  usp))
-        inflight[k] = futs
-        arenas_of[k] = ars
-        state["tasks"] += len(futs)
+        inflight[k] = _submit_unit(
+            ex, n_workers, readers[ri], rgi, _cs, filter=filter,
+            verdict=None if verdicts is None else verdicts.get((ri, rgi)),
+            tctx=_trace.ctx_of(usp), usp=usp)
+        state["tasks"] += len(inflight[k][0])
 
     def fill_window(min_units: int):
         while state["next_j"] < len(order) and (
@@ -3251,36 +3166,23 @@ def pipelined_reads(readers, units, device_for=None, start: int = 0):
     try:
         fill_window(2)  # current unit + at least one planned ahead
         for k in order:
-            futs = inflight.pop(k)
+            futs, arenas = inflight.pop(k)
             state["tasks"] -= len(futs)
             usp = unit_spans.pop(k, None)
-            planned, err = _join_plans(futs, _cs,
-                                       parent=_trace.ctx_of(usp))
-            if usp is not None and "t0_exec" in usp:
-                # the unit span starts when its first plan task RAN
-                # (stamped by the worker; all futures joined above),
-                # not when the window submitted it — queue wait
-                # belongs to the scan's driver time, not the unit
-                usp["t0"] = usp["t0_exec"]
-            if err is not None:
-                _trace.close_span(usp, status="error",
-                                  error=type(err).__name__)
-                raise err
+            ri, rgi = units[k]
+            dev_ctx = (jax.default_device(device_for(k))
+                       if device_for is not None
+                       else contextlib.nullcontext())
             try:
-                with _trace.adopt(_trace.ctx_of(usp)):
-                    if device_for is not None:
-                        with jax.default_device(device_for(k)):
-                            out = _finish_row_group(planned)
-                    else:
-                        # drains; arenas free
-                        out = _finish_row_group(planned)
+                with dev_ctx:  # drains; arenas free
+                    out = _finish_unit(readers[ri], rgi, futs, arenas,
+                                       _cs, filtered=filter is not None,
+                                       usp=usp)
             except BaseException as e:
                 _trace.close_span(usp, status="error",
                                   error=type(e).__name__)
                 raise
             _trace.close_span(usp)
-            for a in arenas_of.pop(k):
-                return_arena(a)
             fill_window(1)
             if _cs is not None:
                 _cs.row_groups += 1
@@ -3304,12 +3206,13 @@ def pipelined_reads(readers, units, device_for=None, start: int = 0):
 
 def read_row_groups_device(reader, rg_indices=None, filter=None,
                            out_sharding=None, gather_to=None):
-    """Yield ``(rg_index, {path: DeviceColumn})`` for several row groups,
-    overlapping host planning with device transfer (see
-    :func:`pipelined_reads`).  Results are identical to calling
-    :func:`read_row_group_device` per index.  With ``filter``, row
-    groups the static verdict proves empty are skipped entirely (not
-    yielded) and the rest decode late-materialized.
+    """Yield ``(rg_index, {path: DeviceColumn})`` for several row groups
+    through the one unit window, :func:`pipelined_reads`, which
+    overlaps host planning with device transfer.  Results are identical
+    to calling :func:`read_row_group_device` per index.  With
+    ``filter``, row groups the static verdict proves empty are skipped
+    entirely (not yielded) and the rest decode late-materialized in the
+    same window.
 
     ``out_sharding`` (a ``NamedSharding`` over the consumer's mesh) /
     ``gather_to`` (a device or local-device index) place the decode
@@ -3334,6 +3237,7 @@ def read_row_groups_device(reader, rg_indices=None, filter=None,
     if rg_indices is None:
         rg_indices = range(reader.row_group_count())
     indices = list(rg_indices)
+    verdicts = None
     if filter is not None:
         from ..filter import bind_filter
 
@@ -3353,13 +3257,10 @@ def read_row_groups_device(reader, rg_indices=None, filter=None,
                 st.bloom_hits += v.bloom_hits
             verdicts[(0, i)] = v
             kept.append(i)
-        for k, out in filtered_pipelined_reads(
-                [reader], [(0, i) for i in kept], device_for,
-                filter=filter, verdicts=verdicts):
-            yield kept[k], out
-        return
+        indices = kept
     for k, out in pipelined_reads([reader], [(0, i) for i in indices],
-                                  device_for):
+                                  device_for, filter=filter,
+                                  verdicts=verdicts):
         yield indices[k], out
 
 

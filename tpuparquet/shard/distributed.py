@@ -477,7 +477,8 @@ class MultiHostScan(_DurableScanMixin):
         the durable per-host checkpoint, the scan budget, and the live
         telemetry (per-host :attr:`progress` status file, ambient
         metrics, automatic post-mortems) apply exactly as there."""
-        from .scan import pipelined_unit_scan, resilient_unit_scan
+        from ..kernels.device import pipelined_reads
+        from .scan import resilient_unit_scan
 
         self._run_t0 = time.monotonic()
         if self.filter is not None and self._next_local == 0:
@@ -489,7 +490,7 @@ class MultiHostScan(_DurableScanMixin):
                 select_pruned=lambda j: j % n == p,
                 select_kept=lambda key: key in local)
         if self.on_error == "raise":
-            gen = pipelined_unit_scan(
+            gen = pipelined_reads(
                 self.readers, self.local_units,
                 lambda i: self.devices[i % len(self.devices)],
                 start=self._next_local, filter=self.filter,

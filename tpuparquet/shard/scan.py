@@ -39,12 +39,13 @@ from ..obs.recorder import flight
 from ..kernels.decode import scatter_to_dense
 from ..kernels.device import (
     DeviceColumn,
+    pipelined_reads,
     read_row_group_device,
     read_row_group_device_resilient,
 )
 
 __all__ = ["ShardedScan", "scan_units", "open_sources",
-           "pipelined_unit_scan", "resilient_unit_scan",
+           "resilient_unit_scan",
            "gather_column", "gather_byte_column",
            "save_cursor_file", "load_cursor_file", "host_cursor_path",
            "checkpoint_every_default"]
@@ -426,26 +427,6 @@ def host_cursor_path(base: str, process_index: int) -> str:
     """Per-host checkpoint file for a multi-process scan: each process
     owns exactly one file (no cross-host write races)."""
     return f"{base}.p{process_index}"
-
-
-def pipelined_unit_scan(readers, units, device_for=None, start: int = 0,
-                        filter=None, verdicts=None):
-    """Yield ``(unit_index, {path: DeviceColumn})`` for ``units[start:]``,
-    overlapping host planning with device transfer/dispatch — the shared
-    pipeline in :func:`tpuparquet.kernels.device.pipelined_reads`, with
-    (file, row-group) units and per-unit device placement.  With
-    ``filter`` the late-materialized pushdown pipeline runs instead
-    (:func:`~tpuparquet.kernels.device.filtered_pipelined_reads`)."""
-    if filter is not None:
-        from ..kernels.device import filtered_pipelined_reads
-
-        yield from filtered_pipelined_reads(
-            readers, units, device_for, start, filter=filter,
-            verdicts=verdicts)
-        return
-    from ..kernels.device import pipelined_reads
-
-    yield from pipelined_reads(readers, units, device_for, start)
 
 
 def resilient_unit_scan(readers, units, device_for, *, start: int = 0,
@@ -1069,7 +1050,8 @@ class ShardedScan(DurableScanMixin):
     :meth:`run` decodes every unit on its round-robin device and returns
     per-unit ``{path: DeviceColumn}`` dicts; results stay device-resident
     and sharded until explicitly gathered.  Host planning of unit N+1
-    overlaps device transfer of unit N (:func:`pipelined_unit_scan`).
+    overlaps device transfer of unit N
+    (:func:`~tpuparquet.kernels.device.pipelined_reads`).
 
     Resumable (SURVEY.md §5 checkpoint/resume — the row group as the
     restart unit): :meth:`state` snapshots a cursor after any number of
@@ -1293,6 +1275,11 @@ class ShardedScan(DurableScanMixin):
         :class:`~tpuparquet.errors.DeadlineExceededError` with the
         cursor intact.
 
+        Fail-fast scans (``on_error="raise"``) run in the one unit
+        window, :func:`~tpuparquet.kernels.device.pipelined_reads`,
+        filtered or not; quarantine mode decodes unit by unit in
+        :func:`resilient_unit_scan`.
+
         Live telemetry (this round): :attr:`progress` ticks at every
         unit boundary (``parquet-tool top`` watches the exported
         status file), units decode under the scan's ambient collector
@@ -1305,7 +1292,7 @@ class ShardedScan(DurableScanMixin):
             # once (a cursor resume already counted them in its run)
             self._count_pruned()
         if self.on_error == "raise":
-            gen = pipelined_unit_scan(
+            gen = pipelined_reads(
                 self.readers, self.units, self.device_for,
                 start=self._next_unit, filter=self.filter,
                 verdicts=self._verdicts)
