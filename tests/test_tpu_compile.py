@@ -160,27 +160,23 @@ def test_chunk_program(one_chip, kind):
     def arr(shape, dtype=u32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    def stream(cnt, w, runs):
-        return arr((cnt // 32 * w,)), arr((4, runs))
+    def stream(slots, cnt, w, runs):
+        return arr((slots, cnt // 32 * w)), arr((slots, 4, runs))
 
-    lev = (tuple(stream(32768, 1, 32) for _ in range(56)),
-           (stream(16384, 1, 32),))
+    lev = (stream(56, 32768, 1, 32), stream(1, 16384, 1, 32))
     lev_sig = ((32768, 1, 32768, True), (16384, 1, 16384, True))
     lanes, shared = 2, (arr((1024,)),)
     if kind == "dict":
-        val = (tuple(stream(32768, 9, 64) for _ in range(56)),
-               (stream(16384, 9, 64),))
+        val = (stream(56, 32768, 9, 64), stream(1, 16384, 9, 64))
         val_sig = (("dict", 32768, 9, 32768, False),
                    ("dict", 16384, 9, 16384, False))
     elif kind == "dict_bytes":
         lanes, shared = 1, (arr((32,), jnp.int32), arr((32,), jnp.uint8))
-        val = (tuple(stream(32768, 1, 32) for _ in range(56)),
-               (stream(16384, 1, 32),))
+        val = (stream(56, 32768, 1, 32), stream(1, 16384, 1, 32))
         val_sig = (("dict_bytes", 32768, 1, 32768, True, 32768, 0),
                    ("dict_bytes", 16384, 1, 16384, True, 16384, 0))
     else:
-        val = (tuple((arr((40960,)),) for _ in range(56)),
-               ((arr((32768,)),),))
+        val = ((arr((56, 40960)),), (arr((1, 32768)),))
         val_sig = (("plain",), ("plain",))
     meta = arr((114, 5), jnp.int32)
     sig = (lev_sig, val_sig, N, N * lanes, lanes, 1)
@@ -204,8 +200,8 @@ def test_chunk_program_lineitem(one_chip, kind):
     if kind == "plain_bytes":
         lanes = 1
         shared = (arr((32768,), i32), arr((1 << 20,), u8))
-        val = ((((arr((4096 * 17 // 32,)), arr((4, 32))),) * 2),
-               ((arr((540672,), u8),),) * 56, ((arr((139264,), u8),),))
+        val = ((arr((2, 4096 * 17 // 32)), arr((2, 4, 32))),
+               (arr((56, 540672), u8),), (arr((1, 139264), u8),))
         val_sig = (("dict_bytes", 4096, 17, 4096, True, 1 << 17, 0),
                    ("plain_bytes",), ("plain_bytes",))
         total = 1 << 25
@@ -213,20 +209,23 @@ def test_chunk_program_lineitem(one_chip, kind):
         spec = (("bytes", ("raw8", 0), ("raw8", 1), ("raw8", 2),
                  ("rle8", 0, 32)), ("rle32", 0, 32))
 
-        def planes(stride):
-            return (arr((1,)), arr((32,), i32), arr((32,)),
-                    arr((3 * stride,), u8), arr((32,), i32),
-                    arr((32,), u8))
+        def planes(slots, stride):
+            return (arr((slots, 1)), arr((slots, 32), i32),
+                    arr((slots, 32)), arr((slots, 3 * stride), u8),
+                    arr((slots, 32), i32), arr((slots, 32), u8))
 
-        val = ((planes(20000),) * 48, (planes(8576),))
+        val = (planes(48, 20000), planes(1, 8576))
         val_sig = (("planes", spec, 20000), ("planes", spec, 8576))
         total = N * lanes
     else:
-        delta = (arr((32768 // 32 * 5,)), arr((1,)), arr((1,)))
-        val = ((delta,) * 32, (delta,))
+        def delta(slots):
+            return (arr((slots, 32768 // 32 * 5)), arr((slots, 1)),
+                    arr((slots, 1)))
+
+        val = (delta(32), delta(1))
         val_sig = (("delta", 32768, 5, True), ("delta", 32768, 4, True))
         total = N * lanes
-    meta = arr((sum(len(g) for g in val), 5), i32)
+    meta = arr((sum(g[0].shape[0] for g in val), 5), i32)
     sig = ((), val_sig, 0, total, lanes, 0)
     _compile(chunk_program, shared, (), val, meta, sig=sig)
 
@@ -263,7 +262,7 @@ def test_chunk_program_dict_bytes_lineitem(one_chip, shape):
 
     n_dict, n_bytes, w, fixed, groups = DICT_BYTES[shape]
     shared = (arr((bucket(n_dict + 1),), i32), arr((bucket(n_bytes),), u8))
-    val = tuple(((arr((cnt // 32 * w,)), arr((4, 32))),) * slots
+    val = tuple((arr((slots, cnt // 32 * w)), arr((slots, 4, 32)))
                 for slots, cnt, _ in groups)
     val_sig = tuple(("dict_bytes", cnt, w, cnt, True, cap, fixed)
                     for _, cnt, cap in groups)

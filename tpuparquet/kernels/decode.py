@@ -404,11 +404,13 @@ def _dict_bytes_gather(dict_offsets, dict_data, idx, non_null,
 # ----------------------------------------------------------------------
 # Chunk program: every data page of one column chunk in one dispatch.
 # Pages that share a kernel's statics and input shapes form a group and
-# decode together from their stacked inputs; each page's valid prefix
-# is then written into the chunk's output at its running offset.  Offsets and counts are runtime int32 data (``meta``), and a
-# group's page count is padded to a bucket (padding slots repeat the
-# group's first page and write nothing), so the compile key holds
-# bucketed shapes, kernel kinds and page statics, never exact counts.
+# decode together from their stacked inputs, which the host stages as
+# one buffer per group and role; each page's valid prefix is then
+# written into the chunk's output at its running offset.  Offsets and
+# counts are runtime int32 data (``meta``), and a group's page count is
+# padded to a bucket (padding slots repeat the group's first page and
+# write nothing), so the compile key holds bucketed shapes, kernel
+# kinds and page statics, never exact counts.
 # ----------------------------------------------------------------------
 
 def _place(out, pages, rows):
@@ -428,13 +430,6 @@ def _place(out, pages, rows):
     return jax.lax.fori_loop(0, pages.shape[0], put, out)
 
 
-def _stack(group, k):
-    # one concatenate: jnp.stack traces an expand_dims per page
-    first = group[0][k]
-    return jnp.concatenate([page[k] for page in group]).reshape(
-        (len(group),) + first.shape)
-
-
 def _expand_pages(bp, tbl, cnt: int, w: int, nbp: int, single: bool):
     """:func:`_expand_stream` of ``g`` pages: stacked ``(g, words)``
     bit-packed words and ``(g, 4, R)`` run tables -> ``(g, cnt)`` u32.
@@ -452,20 +447,20 @@ def _expand_pages(bp, tbl, cnt: int, w: int, nbp: int, single: bool):
 
 
 def _chunk_values(kind, statics, group, shared, rows, lanes):
-    """One value group -> ``(pages, width)``, in output units."""
+    """One value group (its stacked arrays, one per role) ->
+    ``(pages, width)``, in output units."""
     if kind in ("plain", "plain_bytes"):
-        return _stack(group, 0)
-    arrs = tuple(_stack(group, k) for k in range(len(group[0])))
+        return group[0]
     if kind == "planes":
         spec, stride = statics
         return jax.lax.map(lambda page: _planes_words(
-            *page, spec, stride, lanes, stride), arrs)
+            *page, spec, stride, lanes, stride), group)
     if kind == "delta":
         first = jax.lax.bitcast_convert_type(rows[:, 3:5], jnp.uint32)
         return jax.lax.map(lambda page: _delta_page(
-            page[0], page[1], *statics), (arrs, first))
+            page[0], page[1], *statics), (group, first))
     icnt, iw, inbp, isingle = statics[:4]
-    idx = _expand_pages(arrs[0], arrs[1], icnt, iw, inbp,
+    idx = _expand_pages(group[0], group[1], icnt, iw, inbp,
                         isingle).astype(jnp.int32)
     if kind == "dict":
         # one 1-D gather per lane, interleaved per page: a row gather
@@ -525,10 +520,11 @@ def chunk_program(shared, lev_groups, val_groups, meta, sig):
     (:func:`planes_to_words`); ``"delta"`` ``(n_vals, w, wide)``
     (:func:`_delta_page`).  Then the bucketed level and value totals;
     u32 lanes per value; the column's max definition level.
-    ``lev_groups`` and ``val_groups`` hold each group's pages' staged
-    arrays (level and index streams as ``(bp_words, table)``, PLAIN
-    values and bytes as ``(words,)``, the transports' as they are
-    staged); ``shared`` the chunk's dictionary arrays.  ``meta`` has
+    ``lev_groups`` and ``val_groups`` hold each group's arrays, one
+    per role, each the group's page slots stacked on a leading axis
+    (level and index streams as ``(bp_words, table)``, PLAIN values
+    and bytes as ``(words,)``, the transports' as they are staged);
+    ``shared`` the chunk's dictionary arrays.  ``meta`` has
     one int32 row ``(offset, length, non_null, aux0, aux1)`` per page
     slot, level groups' slots first, then the value groups', in group
     order; ``aux`` holds a delta page's first value as (lo, hi) words.
@@ -537,16 +533,16 @@ def chunk_program(shared, lev_groups, val_groups, meta, sig):
     levels, mask and positions are None for a required column."""
     lev_sig, val_sig, lev_len, val_len, lanes, max_def = sig
     lev, vals, slot = [], [], 0
-    for (cnt, w, nbp, single), group in zip(lev_sig, lev_groups):
-        lev.append((_expand_pages(_stack(group, 0), _stack(group, 1),
-                                  cnt, w, nbp, single).astype(jnp.int32),
-                    meta[slot : slot + len(group)]))
-        slot += len(group)
+    for (cnt, w, nbp, single), (bp, tbl) in zip(lev_sig, lev_groups):
+        lev.append((_expand_pages(bp, tbl, cnt, w, nbp,
+                                  single).astype(jnp.int32),
+                    meta[slot : slot + bp.shape[0]]))
+        slot += bp.shape[0]
     for (kind, *statics), group in zip(val_sig, val_groups):
-        rows = meta[slot : slot + len(group)]
+        rows = meta[slot : slot + group[0].shape[0]]
         vals.append((_chunk_values(kind, statics, group, shared, rows,
                                    lanes), rows))
-        slot += len(group)
+        slot += group[0].shape[0]
     if not lev:
         return None, _assemble(vals, val_len), None, None
     dl = _assemble(lev, lev_len)

@@ -88,9 +88,13 @@ class DecodeStats:
     # stager and are not counted here.
     bytes_staged: int = 0
     # arrays handed to jax.device_put by the batched stager (each split
-    # piece counts): with bytes_staged, whether staging is bound by
-    # bytes or by the number of puts
+    # piece counts, a fused chunk's stacked buffer counts once): with
+    # bytes_staged, whether staging is bound by bytes or by the number
+    # of puts
     pieces_staged: int = 0
+    # page arrays of fused chunks that shipped inside a stacked buffer
+    # (one piece per group and role) rather than as pieces of their own
+    stacked_arrays: int = 0
     # device programs each column's finish() enqueued: one per chunk
     # decoded by a chunk program (chunks_fused counts those chunks,
     # pages_fused their data pages); on the per-page path each page
@@ -291,7 +295,7 @@ class DecodeStats:
         "pages_device_planes", "pages_device_delta_lanes",
         "pages_device_encoded", "pages_host_values", "values",
         "bytes_compressed", "bytes_uncompressed", "bytes_staged",
-        "pieces_staged", "chunks_fused", "pages_fused",
+        "pieces_staged", "stacked_arrays", "chunks_fused", "pages_fused",
         "programs_dispatched", "dict_bytes_pages", "dict_bytes_fixed_pages",
         "bytes_read", "read_s",
         "native_fallbacks", "pages_crc_verified", "crc_mismatches",
@@ -364,6 +368,7 @@ class DecodeStats:
             "bytes_uncompressed": self.bytes_uncompressed,
             "bytes_staged": self.bytes_staged,
             "pieces_staged": self.pieces_staged,
+            "stacked_arrays": self.stacked_arrays,
             "chunks_fused": self.chunks_fused,
             "pages_fused": self.pages_fused,
             "programs_dispatched": self.programs_dispatched,
@@ -442,7 +447,8 @@ class DecodeStats:
             f"(x{d['compression_ratio']}); "
             f"{d['wall_s']:.4f}s = {d['values_per_sec']:,.0f} values/s"
             + (f"; staged {d['bytes_staged']:,}B to device in "
-               f"{d['pieces_staged']:,} pieces"
+               f"{d['pieces_staged']:,} pieces "
+               f"({d['stacked_arrays']:,} page arrays stacked)"
                if d["bytes_staged"] else "")
             + (f"; plan {d['plan_s']:.3f}s (cpu {d['plan_cpu_s']:.3f}s)"
                f" / plan wait {d['plan_wait_s']:.3f}s / transfer "
